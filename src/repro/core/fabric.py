@@ -251,13 +251,6 @@ LOCAL_DDR = FabricModel(
     atomic_us=0.02,
 )
 
-# TPU-side constants (the adaptation targets; used by roofline + tiering).
-TPU_V5E_HBM_GBPS = 819.0
-TPU_V5E_PEAK_BF16_TFLOPS = 197.0
-TPU_V5E_ICI_GBPS_PER_LINK = 50.0
-PCIE_HOST_GBPS = 32.0  # host<->HBM staging bandwidth (PCIe gen4 x16 class)
-
-
 class SimClock:
     """Deterministic discrete-event clock.
 

@@ -59,6 +59,9 @@ from repro.core.placement import PlacementPlan, PlacementPolicy
 
 TieringMode = Literal["none", "host_offload", "fsdp_stream"]
 
+#: Modeled HBM stream rate (GB/s) that prices a synthesized profile's compute.
+_MODELED_HBM_GBPS = 819.0
+
 
 @dataclasses.dataclass(frozen=True)
 class TieringConfig:
@@ -90,48 +93,48 @@ class TieringConfig:
 
 @functools.cache
 def supports_host_offload() -> bool:
-    """Probe whether the current backend accepts pinned_host memory kinds."""
-    try:
-        dev = jax.devices()[0]
-        sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
-        x = jax.device_put(jnp.zeros((8,), jnp.float32), sharding)
-        jax.block_until_ready(x)
-        return True
-    except Exception:  # noqa: BLE001 - backend support probe
+    """Whether demoted objects can live in ``pinned_host`` memory here.
+
+    Decided by the platform: on a TPU host DRAM is the remote tier, so the
+    probe placement must succeed and any failure raises; other backends
+    return False and demotions stay in the plan and the memory pool.
+    """
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
         return False
+    sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
+    jax.block_until_ready(jax.device_put(jnp.zeros((8,), jnp.float32), sharding))
+    return True
 
 
 @functools.cache
-def _offload_spmd_probe(mesh_shape: tuple, mesh_axes: tuple) -> bool:
-    try:
-        mesh = jax.make_mesh(mesh_shape, mesh_axes)
-        dev_sh = NamedSharding(mesh, P(None, mesh_axes[-1]))
-        host_sh = NamedSharding(mesh, P(None, mesh_axes[-1]),
-                                memory_kind="pinned_host")
+def _offload_spmd_probe(mesh: jax.sharding.Mesh) -> bool:
+    axis = mesh.axis_names[-1]
+    dev_sh = NamedSharding(mesh, P(None, axis))
+    host_sh = NamedSharding(mesh, P(None, axis), memory_kind="pinned_host")
 
-        def step(p, m):
-            m2 = 0.9 * m + 0.1 * p.astype(jnp.float32)
-            return (p.astype(jnp.float32) - m2).astype(p.dtype), m2
+    def step(p, m):
+        m2 = 0.9 * m + 0.1 * p.astype(jnp.float32)
+        return (p.astype(jnp.float32) - m2).astype(p.dtype), m2
 
-        pa = jax.ShapeDtypeStruct((16, mesh.shape[mesh_axes[-1]] * 8), jnp.bfloat16)
-        ma = jax.ShapeDtypeStruct(pa.shape, jnp.float32)
-        jax.jit(step, in_shardings=(dev_sh, host_sh),
-                out_shardings=(dev_sh, host_sh)).lower(pa, ma).compile()
-        return True
-    except Exception:  # noqa: BLE001 - backend support probe
-        return False
+    pa = jax.ShapeDtypeStruct((16, mesh.shape[axis] * 8), jnp.bfloat16)
+    ma = jax.ShapeDtypeStruct(pa.shape, jnp.float32)
+    jax.jit(step, in_shardings=(dev_sh, host_sh),
+            out_shardings=(dev_sh, host_sh)).lower(pa, ma).compile()
+    return True
 
 
 def supports_host_offload_spmd(mesh: jax.sharding.Mesh) -> bool:
     """Whether pinned_host in/out shardings compile under SPMD on this mesh.
 
-    True on TPU backends; False on XLA-CPU (the dry-run container), which
-    rejects memory-space annotations in the SPMD partitioner — the optimizer
-    then falls down the bf16/int8 moment ladder instead (DESIGN.md §2).
+    Decided by the mesh's platform: on TPU the probe compile must succeed
+    (a failure raises); XLA-CPU (the dry-run container) rejects memory-space
+    annotations in the SPMD partitioner, so it returns False and the
+    optimizer falls down the bf16/int8 moment ladder instead (DESIGN.md §2).
     """
-    return _offload_spmd_probe(
-        tuple(mesh.shape.values()), tuple(mesh.shape.keys())
-    )
+    if mesh.devices.flat[0].platform != "tpu":
+        return False
+    return _offload_spmd_probe(mesh)
 
 
 def plan_for_params(
@@ -188,12 +191,12 @@ def plan_for_params(
                 )
             )
     if config.local_fraction == "auto" and profile is None:
-        from repro.core.fabric import TPU_V5E_HBM_GBPS
         from repro.core.sizing import synthetic_profile
 
-        # one read of every leaf per step at HBM stream rate approximates the
-        # step's compute floor — enough for the solver to price demotions
-        compute_us = catalog.total_bytes / (TPU_V5E_HBM_GBPS * 1e3)
+        # one read of every leaf per step at a modeled HBM stream rate
+        # approximates the step's compute floor — enough for the solver to
+        # price demotions; a model input, never reported as a device rate
+        compute_us = catalog.total_bytes / (_MODELED_HBM_GBPS * 1e3)
         profile = synthetic_profile(catalog, compute_us_per_step=compute_us,
                                     source="plan_for_params")
     plan = PlacementPolicy().plan(

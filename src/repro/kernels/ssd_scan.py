@@ -21,20 +21,29 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import resolve_interpret
 
 
-def _kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, y_ref, state, *, n_chunks):
+def _kernel(x_ref, b_ref, c_ref, dtr_ref, dtc_ref, cumr_ref, cumc_ref,
+            y_ref, state):
+    # dt and cum arrive twice, as a (1, Q) row and a (Q, 1) column: Mosaic
+    # needs the last two block dims tile-aligned or whole, and the decay
+    # matrix is an outer difference of the two orientations.
     cb = pl.program_id(2)
 
     @pl.when(cb == 0)
     def _init():
         state[...] = jnp.zeros_like(state)
 
-    x = x_ref[0, 0, 0].astype(jnp.float32)     # (Q, P)
-    Bm = b_ref[0, 0, 0].astype(jnp.float32)    # (Q, N)
-    Cm = c_ref[0, 0, 0].astype(jnp.float32)    # (Q, N)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)   # (Q,)
-    cum = cum_ref[0, 0, 0].astype(jnp.float32)
+    x = x_ref[0, 0, 0].astype(jnp.float32)        # (Q, P)
+    Bm = b_ref[0, 0, 0].astype(jnp.float32)       # (Q, N)
+    Cm = c_ref[0, 0, 0].astype(jnp.float32)       # (Q, N)
+    dt_row = dtr_ref[0, 0, 0].astype(jnp.float32)     # (1, Q)
+    dt_col = dtc_ref[0, 0, 0].astype(jnp.float32)     # (Q, 1)
+    cum_row = cumr_ref[0, 0, 0].astype(jnp.float32)   # (1, Q)
+    cum_col = cumc_ref[0, 0, 0].astype(jnp.float32)   # (Q, 1)
     Q = x.shape[0]
-    total = cum[Q - 1]
+    # cum[Q-1] as a masked lane sum: a (1, 1) slice at lane Q-1 keeps that
+    # lane offset, which Mosaic cannot broadcast down a column
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1) == Q - 1
+    total = jnp.sum(jnp.where(last, cum_row, 0.0), axis=1, keepdims=True)
 
     # intra-chunk: scores (Q,Q) = C_i . B_j, decay L[i,j] = exp(cum_i - cum_j)
     scores = jax.lax.dot_general(
@@ -42,7 +51,7 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, y_ref, state, *, n_chunks):
     )
     li = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    lmat = jnp.exp(cum[:, None] - cum[None, :]) * dt[None, :]
+    lmat = jnp.exp(cum_col - cum_row) * dt_row
     lmat = jnp.where(li >= lj, lmat, 0.0)
     y_intra = jax.lax.dot_general(
         scores * lmat, x, (((1,), (0,)), ((), ())),
@@ -53,10 +62,10 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, y_ref, state, *, n_chunks):
     y_inter = jax.lax.dot_general(
         Cm, state[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * jnp.exp(cum)[:, None]                   # (Q, P)
+    ) * jnp.exp(cum_col)                          # (Q, P)
 
     # chunk-local state and carry update
-    decay_out = (jnp.exp(total - cum) * dt)[:, None] * Bm       # (Q, N)
+    decay_out = jnp.exp(total - cum_col) * dt_col * Bm          # (Q, N)
     s_local = jax.lax.dot_general(
         x, decay_out, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -85,18 +94,20 @@ def _ssd_call(xc, bc, cc, dtc, cum, *, interpret: bool) -> jax.Array:
     B, H, nc, Q, P = xc.shape
     N = bc.shape[-1]
     grid = (B, H, nc)
+
+    def chunk_spec(*tail):
+        return pl.BlockSpec((1, 1, 1, *tail),
+                            lambda b, h, c: (b, h, c) + (0,) * len(tail))
+
+    row, col = chunk_spec(1, Q), chunk_spec(Q, 1)
     return pl.pallas_call(
-        functools.partial(_kernel, n_chunks=nc),
+        _kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q, N), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q, N), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
+        in_specs=[chunk_spec(Q, P), chunk_spec(Q, N), chunk_spec(Q, N),
+                  row, col, row, col],
+        out_specs=chunk_spec(Q, P),
         out_shape=jax.ShapeDtypeStruct((B, H, nc, Q, P), xc.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xc, bc, cc, dtc, cum)
+    )(xc, bc, cc, dtc[..., None, :], dtc[..., None], cum[..., None, :],
+      cum[..., None])

@@ -10,18 +10,26 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None) -> jax.sharding.Mesh:
+    """The one mesh constructor: every axis is ``AxisType.Auto``.
+
+    ``jax.make_mesh`` gives ``Explicit`` axes by default since JAX 0.7, and
+    ``with_sharding_constraint`` (what the logical-axis rules lower to)
+    accepts only ``Auto`` axes. ``devices`` defaults to ``jax.devices()``.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod (v5e); multi-pod adds a 2-pod DCN axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_smoke_mesh(shape=(1, 1), axes=("data", "model")):
-    """Single-device mesh for CPU smoke tests of the sharded code paths."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 # The measured-overlap recipe (PR 8): what the StreamingExecutor does by hand
@@ -58,9 +66,3 @@ def apply_latency_hiding_flags(*, target: str = "gpu",
         env["XLA_FLAGS"] = current
     return current
 
-
-# Hardware constants (TPU v5e), used by the roofline analysis.
-PEAK_BF16_FLOPS = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW_PER_LINK = 50e9            # bytes/s per link (~50 GB/s)
-CHIPS_PER_POD = 256

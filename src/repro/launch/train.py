@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config, reduced_config
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.sharding import use_mesh, use_rules
 from repro.optim import AdamWConfig, CompressionConfig
 from repro.train.loop import LoopConfig, train
@@ -53,6 +55,7 @@ def main() -> None:
 
     if args.distributed:
         jax.distributed.initialize()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:
@@ -62,7 +65,7 @@ def main() -> None:
     if args.mesh:
         sizes = tuple(int(s) for s in args.mesh.split(","))
         axes = ("data", "model", "pod")[: len(sizes)]
-        mesh = jax.make_mesh(sizes, axes)
+        mesh = make_mesh(sizes, axes)
 
     step_cfg = TrainStepConfig(
         remat=args.remat,

@@ -77,22 +77,6 @@ def current_mesh() -> Mesh | None:
     return _ctx.mesh
 
 
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """Version-compat ``AbstractMesh`` constructor.
-
-    Newer JAX takes ``AbstractMesh(axis_sizes, axis_names)``; 0.4.x takes a
-    single tuple of ``(name, size)`` pairs and raises ``TypeError`` on the
-    two-argument form. Spec resolution only needs ``mesh.shape``, which both
-    constructions provide identically.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes))
-        )
-
-
 def _physical(axes: tuple[str, ...] | str | None, mesh: Mesh) -> tuple[str, ...]:
     if axes is None:
         return ()

@@ -191,6 +191,7 @@ class ServingEngine:
             if acfg is not None else None
         )
         self._wave = 0
+        self.last_logits: jax.Array | None = None
         self.autoscale_log: list[dict] = []
         self.catalog = self._build_catalog()
         self.placement = self._decide_cache_placement()
@@ -771,6 +772,10 @@ class ServingEngine:
     def generate(self, prompts: np.ndarray, max_new: int = 16) -> np.ndarray:
         """Greedy batched generation. prompts: (B, P) int32, B <= max_batch.
 
+        After the call ``last_logits`` holds the final decode step's logits
+        (all ``max_batch`` lanes), the prediction that follows the last
+        returned token.
+
         Prefill is performed through the decode path (token-at-a-time);
         production prefill uses the chunked forward (see launch.dryrun
         prefill cells) — this engine is the correctness/latency harness.
@@ -788,21 +793,25 @@ class ServingEngine:
         cache = self.cache
         logits = None
         miss0 = self.expert_store.misses if self.expert_store else 0
+        # each timed window ends in block_until_ready: the step gauges
+        # cover the step's device time, not only the time to enqueue it
         for t in range(P):
             t0 = time.perf_counter()
-            logits, cache = self._decode(cache, toks[:, t:t + 1])
+            logits, cache = jax.block_until_ready(
+                self._decode(cache, toks[:, t:t + 1]))
             step_us.append((time.perf_counter() - t0) * 1e6)
         out = []
         cur = jnp.argmax(logits[:, :, : self.cfg.vocab_size], axis=-1).astype(jnp.int32)
         for _ in range(max_new):
             out.append(np.asarray(cur))
             t0 = time.perf_counter()
-            logits, cache = self._decode(cache, cur)
+            logits, cache = jax.block_until_ready(self._decode(cache, cur))
             step_us.append((time.perf_counter() - t0) * 1e6)
             cur = jnp.argmax(
                 logits[:, :, : self.cfg.vocab_size], axis=-1
             ).astype(jnp.int32)
         self.cache = cache
+        self.last_logits = logits
         if self.expert_store is not None:
             store = self.expert_store
             self.telemetry.gauge("serving.expert_hit_rate", store.hit_rate())

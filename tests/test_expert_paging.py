@@ -19,6 +19,7 @@ from repro.configs import get_config, reduced_config
 from repro.core.placement import expert_slab_name, expert_slab_objects
 from repro.core.pool import MemoryPool
 from repro.core.sizing import advise_expert_residency, decode_state_census
+from repro.launch.mesh import make_mesh
 from repro.models import get_model
 from repro.models import moe as MOE
 from repro.serving import EngineConfig, ServingEngine
@@ -172,7 +173,7 @@ def test_paging_rejects_non_moe_and_lane_mode(moe_setup):
 
 # -- dispatch-path regressions (satellites 1 + 2) ---------------------------
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("groups", [None, 1, 2, 4, 8])

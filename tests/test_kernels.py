@@ -96,17 +96,26 @@ class TestBackendHelper:
 
         monkeypatch.delenv(kernels.BACKEND_ENV, raising=False)
         backend = kernels.kernel_backend()
-        on_tpu = jax.devices()[0].platform == "tpu"
+        on_tpu = jax.default_backend() == "tpu"
         assert backend == ("pallas" if on_tpu else "interpret")
         assert kernels.resolve_interpret(None) is (not on_tpu)
 
-    @pytest.mark.parametrize("choice,interpret", [
-        ("pallas", False), ("interpret", True),
+    @pytest.mark.parametrize("choice,platform,interpret", [
+        pytest.param("pallas", "cpu", False, id="pallas-False"),
+        pytest.param("pallas", "tpu", False, id="pallas-tpu-False"),
+        pytest.param("interpret", "cpu", True, id="interpret-True"),
+        # refused: interpreting on a TPU would hide the chip
+        pytest.param("interpret", "tpu", None, id="interpret-tpu-refused"),
     ])
-    def test_env_override(self, monkeypatch, choice, interpret):
+    def test_env_override(self, monkeypatch, choice, platform, interpret):
         from repro import kernels
 
+        monkeypatch.setattr(kernels.jax, "default_backend", lambda: platform)
         monkeypatch.setenv(kernels.BACKEND_ENV, choice)
+        if interpret is None:
+            with pytest.raises(RuntimeError, match="interpret on a TPU"):
+                kernels.kernel_backend()
+            return
         assert kernels.kernel_backend() == choice
         assert kernels.resolve_interpret(None) is interpret
 

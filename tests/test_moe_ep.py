@@ -20,6 +20,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config, reduced_config  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import moe as MOE  # noqa: E402
 from repro.models.sharding import use_mesh  # noqa: E402
 
@@ -35,7 +36,7 @@ multi_device = pytest.mark.skipif(
 def test_ep_matches_dense_fwd_and_grads(arch):
     cfg = reduced_config(get_config(arch), dtype=jnp.float32,
                          capacity_factor=8.0)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     p = MOE.moe_init(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
 

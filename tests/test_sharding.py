@@ -7,9 +7,9 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config, reduced_config
+from repro.launch.mesh import make_mesh
 from repro.models import get_model
 from repro.models.sharding import (
-    abstract_mesh,
     batch_pspec_tree,
     cache_pspec_tree,
     opt_pspec_tree,
@@ -23,7 +23,7 @@ from repro.models.sharding import (
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 class TestResolveSpec:
@@ -32,9 +32,8 @@ class TestResolveSpec:
         assert spec == P("data", "model")
 
     def test_divisibility_drops_axis(self):
-        # abstract 16x16 production mesh (no devices needed for specs);
-        # abstract_mesh papers over the JAX-version constructor change
-        m = abstract_mesh((16, 16), ("data", "model"))
+        # abstract 16x16 production mesh (no devices needed for specs)
+        m = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
         # kv_heads=1 can't shard over a 16-way model axis
         spec = resolve_spec((64, 1), ("batch", "kv_heads"), m)
         assert spec[1] is None

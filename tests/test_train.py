@@ -114,3 +114,39 @@ def test_straggler_watchdog_detects(monkeypatch, tiny_cfg):
                 LoopConfig(steps=20, batch=2, seq=16, log_every=100),
                 fault_hook=hook)
     assert any(e["step"] >= 15 for e in res.straggler_events)
+
+
+def test_mesh_run_places_state_and_resumes(tiny_cfg, tmp_path):
+    """Under a mesh the loop creates params in their NamedSharding, keeps
+    that layout through the step and a checkpoint restore, and computes
+    the same losses as the meshless run."""
+    from jax.sharding import NamedSharding
+
+    from repro.launch.mesh import make_mesh
+    from repro.models.sharding import use_mesh
+
+    opt = AdamWConfig(lr=1e-3, warmup_steps=0)
+    loop = dict(steps=8, batch=2, seq=16, log_every=100)
+    ref = train(tiny_cfg, TrainStepConfig(), opt, LoopConfig(**loop))
+
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    ckpt = dict(ckpt_dir=str(tmp_path / "ckpt"), ckpt_every=4)
+
+    class Stop(Exception):
+        pass
+
+    def stop_at_6(step):
+        if step == 6:
+            raise Stop()
+
+    with use_mesh(mesh):
+        with pytest.raises(Stop):
+            train(tiny_cfg, TrainStepConfig(), opt,
+                  LoopConfig(**loop, **ckpt), fault_hook=stop_at_6)
+        resumed = train(tiny_cfg, TrainStepConfig(), opt,
+                        LoopConfig(**loop, **ckpt))
+    assert resumed.restored_from == 4
+    for leaf in jax.tree.leaves(resumed.params):
+        assert isinstance(leaf.sharding, NamedSharding)
+        assert leaf.sharding.mesh == mesh
+    np.testing.assert_allclose(resumed.losses, ref.losses[4:], rtol=1e-5)
