@@ -31,9 +31,18 @@ replays the identical control flow on a :class:`SimClock` through a
 engine's own wall-clock measurements — so ``predicted vs measured`` error is
 a property of the *model*, not of hand-tuned constants. Both sides record
 spans into one :class:`~repro.core.telemetry.Telemetry` (wall tracks
-``wall/...`` via :meth:`Telemetry.wall_now_us`, simulated tracks
+``wall/...`` via :meth:`Telemetry.wall_span`, simulated tracks
 ``sim/...``), so a single exported Perfetto trace shows the real
 fetch/compute overlap next to the simulated timeline.
+
+The wall spans are also profiler annotations, made whether or not the
+telemetry is enabled, so a ``jax.profiler`` trace of a run shows on the
+device's clock where the host was: ``dolma:exec.pass`` around a pass, and
+inside it ``exec.input`` (the input's copy to the device), ``exec.barrier``
+(waiting for a streamed stage's copy), ``exec.stage`` holding
+``exec.dispatch`` (launching the stage's kernel) and ``exec.sync`` (waiting
+for it), and ``exec.commit``; the fetch worker's thread records
+``dolma:fabric.read`` / ``fabric.write`` with the bytes moved.
 
 Outputs are bit-identical to the untiered oracle by construction: prefetch
 on, prefetch off, and all-local runs execute the same jitted kernels on the
@@ -146,22 +155,22 @@ class HostFetchEngine:
     # -- transfers ---------------------------------------------------------
     def _transfer(self, kind: str, name: str,
                   payloads: dict[str, Any], pace: bool) -> dict[str, Any]:
-        tel = self.telemetry
-        w0 = tel.wall_now_us() if tel.enabled else 0.0
-        t0 = time.perf_counter()
-        nbytes = int(sum(int(np.asarray(a).nbytes if kind == "write"
-                             else a.nbytes) for a in payloads.values()))
-        if pace:
-            sleep_us = self.pace_us(kind, nbytes)
-            if sleep_us > 0.0:
-                time.sleep(sleep_us * 1e-6)
-        if kind == "read":
-            out = {k: jax.device_put(a) for k, a in payloads.items()}
-            for a in out.values():
-                a.block_until_ready()
-        else:
-            out = {k: np.asarray(a) for k, a in payloads.items()}
-        us = (time.perf_counter() - t0) * 1e6
+        nbytes = int(sum(int(a.nbytes) for a in payloads.values()))
+        with self.telemetry.wall_span(
+                f"fabric.{kind}", record=kind, track=self.track, cat="io",
+                stage=name, nbytes=nbytes):
+            t0 = time.perf_counter()
+            if pace:
+                sleep_us = self.pace_us(kind, nbytes)
+                if sleep_us > 0.0:
+                    time.sleep(sleep_us * 1e-6)
+            if kind == "read":
+                out = {k: jax.device_put(a) for k, a in payloads.items()}
+                for a in out.values():
+                    a.block_until_ready()
+            else:
+                out = {k: np.asarray(a) for k, a in payloads.items()}
+            us = (time.perf_counter() - t0) * 1e6
         with self._lock:
             self.n_ops += 1
             if kind == "read":
@@ -170,12 +179,6 @@ class HostFetchEngine:
                 self.bytes_written += nbytes
             if pace:
                 self.measurements.append((kind, nbytes, us))
-        if tel.enabled:
-            tel.record_span(kind, track=self.track, begin_us=w0,
-                            end_us=tel.wall_now_us(), cat="io",
-                            obj=name, nbytes=nbytes)
-            tel.count(f"exec.bytes_{'read' if kind == 'read' else 'written'}",
-                      nbytes, track=self.track)
         return out
 
     def fetch(self, name: str, payloads: dict[str, np.ndarray],
@@ -365,10 +368,15 @@ class StreamingExecutor:
         """One measured pass over the chain. With ``prefetch`` on, remote
         stage *j*'s read is posted before stage *i*'s compute (i < j next
         remote); off, every read is a demand fetch the compute waits for."""
+        with self.telemetry.wall_span("exec.pass"):
+            return self._run(x)
+
+    def _run(self, x: np.ndarray) -> ExecResult:
         tel = self.telemetry
         eng = self.engine
-        x = jax.device_put(np.asarray(x))
-        jax.block_until_ready(x)
+        with tel.wall_span("exec.input"):
+            x = jax.device_put(np.asarray(x))
+            jax.block_until_ready(x)
         remote = [i for i, st in enumerate(self.stages)
                   if st.tier is Tier.REMOTE]
         futures: dict[int, Future] = {}
@@ -398,38 +406,32 @@ class StreamingExecutor:
                 fut = futures.pop(i, None)
                 if fut is None:  # demand fetch (prefetch off, or mispost)
                     fut = eng.fetch(st.name, self._host_store[i])
-                w0 = tel.wall_now_us() if tel.enabled else 0.0
                 t0 = time.perf_counter()
-                params = fut.result()  # the deferred access barrier
-                wait_us = (time.perf_counter() - t0) * 1e6
-                stage_wait[st.name] = wait_us
+                with tel.wall_span("exec.barrier", record="stall:barrier",
+                                   track=self.track, cat="stall",
+                                   stage=st.name):
+                    params = fut.result()  # the deferred access barrier
+                stage_wait[st.name] = (time.perf_counter() - t0) * 1e6
                 fetched += st.nbytes
-                if tel.enabled:
-                    tel.record_span("stall:barrier", track=self.track,
-                                    begin_us=w0, end_us=tel.wall_now_us(),
-                                    cat="stall", obj=st.name)
                 if self.prefetch:
                     # dual buffer: post the next remote read before computing
                     post_next(i)
             t0 = time.perf_counter()
-            w0 = tel.wall_now_us() if tel.enabled else 0.0
-            x = self._compute_stage(st, params, x)
-            jax.block_until_ready(x)
+            with tel.wall_span("exec.stage", record=f"compute:{st.name}",
+                               track=self.track, cat="compute",
+                               stage=st.name, op=st.op):
+                with tel.wall_span("exec.dispatch", stage=st.name, op=st.op):
+                    x = self._compute_stage(st, params, x)
+                with tel.wall_span("exec.sync"):
+                    jax.block_until_ready(x)
             stage_compute[st.name] = (time.perf_counter() - t0) * 1e6
-            if tel.enabled:
-                tel.record_span(f"compute:{st.name}", track=self.track,
-                                begin_us=w0, end_us=tel.wall_now_us(),
-                                cat="compute", op=st.op)
         if self.commit_output:
-            with tel.wall_span("commit", track=self.track, cat="io"):
+            with tel.wall_span("exec.commit", record="commit",
+                               track=self.track, cat="io"):
                 eng.write("output", {"y": x}).result()
-        elapsed_us = (time.perf_counter() - t_start) * 1e6
-        if tel.enabled:
-            tel.count("exec.runs")
-            tel.count("exec.elapsed_us", elapsed_us)
         return ExecResult(
             output=x,
-            elapsed_us=elapsed_us,
+            elapsed_us=(time.perf_counter() - t_start) * 1e6,
             stage_compute_us=stage_compute,
             stage_wait_us=stage_wait,
             prefetch=self.prefetch,

@@ -12,6 +12,12 @@ exported. :class:`Telemetry` is that place:
     records begin/end on the *simulated* fabric clock (explicit-time
     recording via :meth:`Telemetry.record_span` for callers that compute
     ``(start, end)`` analytically, which is most of the simulator);
+  * **wall spans** — ``with tel.wall_span("exec.barrier", stage=...)``
+    around real work on the host: always a profiler annotation
+    ``dolma:<name>`` carrying its keyword arguments as stats, so that a
+    ``jax.profiler`` trace puts the program's own spans on the device
+    trace's clock; given ``record=``, also an in-memory span while the
+    instance is enabled;
   * a **counter/gauge registry** — monotonically accumulating counters
     (cache hits/misses, prefetch accuracy inputs, bytes moved per tier and
     per pool node, stall-µs vs. overlap-µs) and last-value gauges
@@ -24,10 +30,12 @@ exported. :class:`Telemetry` is that place:
 
 Telemetry is process-wide *but injectable*: components accept an optional
 ``telemetry=`` and default to the shared :data:`NULL_TELEMETRY`, whose
-recorders return immediately — tracing disabled is the default and changes
-no benchmark number (telemetry only ever *reads* the clock, never advances
-it; the reconciliation tests in ``tests/test_telemetry.py`` assert both
-properties).
+recorders return immediately — in-memory tracing disabled is the default
+and changes no benchmark number (telemetry only ever *reads* the clock,
+never advances it; the reconciliation tests in ``tests/test_telemetry.py``
+assert both properties). The profiler annotations of wall spans are made
+whether or not an instance is enabled: a profiler being on is their only
+switch, and with none on each costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -36,7 +44,9 @@ import dataclasses
 import json
 import threading
 import time
-from typing import Any, Iterator
+from typing import Any, ContextManager, Iterator
+
+from jax.profiler import TraceAnnotation
 
 # span categories (the event taxonomy, DESIGN.md §9):
 #   compute   — time the compute timeline advanced doing work
@@ -47,6 +57,9 @@ from typing import Any, Iterator
 #   serve     — serving waves (wall-clock track)
 #   span      — anything recorded via the generic ``span()`` context manager
 SPAN_CATS = ("compute", "stall", "io", "step", "migration", "serve", "span")
+
+#: prefix of every wall span's profiler annotation
+TRACE_PREFIX = "dolma:"
 
 # categories whose durations tile a compute timeline end-to-end: their sum
 # reconciles with the simulator's elapsed_us (asserted in tests)
@@ -224,19 +237,32 @@ class Telemetry:
                 self._wall_origin = now
             return (now - self._wall_origin) * 1e6
 
+    def wall_span(self, name: str, *, record: str | None = None,
+                  track: str = "wall", cat: str = "span",
+                  **stats: Any) -> ContextManager[None]:
+        """Span over a ``with`` body measured on the real (wall) clock.
+
+        Always a profiler annotation ``dolma:<name>`` (``name`` a stable
+        dotted name such as ``exec.barrier``) with ``stats`` as its stats.
+        While enabled and given ``record``, also an in-memory span of that
+        name on ``track`` with ``stats`` as its args, timed by
+        :meth:`wall_now_us`.
+        """
+        ann = TraceAnnotation(TRACE_PREFIX + name, **stats)
+        if record is None or not self.enabled:
+            return ann
+        return self._recorded(ann, record, track, cat, stats)
+
     @contextlib.contextmanager
-    def wall_span(self, name: str, *, track: str, cat: str = "span",
-                  **args: Any) -> Iterator[None]:
-        """Span over a ``with`` body measured on the real (wall) clock."""
-        if not self.enabled:
-            yield
-            return
-        t0 = self.wall_now_us()
-        try:
-            yield
-        finally:
-            self.record_span(name, track=track, begin_us=t0,
-                             end_us=self.wall_now_us(), cat=cat, **args)
+    def _recorded(self, ann: TraceAnnotation, name: str, track: str,
+                  cat: str, args: dict[str, Any]) -> Iterator[None]:
+        with ann:
+            t0 = self.wall_now_us()
+            try:
+                yield
+            finally:
+                self.record_span(name, track=track, begin_us=t0,
+                                 end_us=self.wall_now_us(), cat=cat, **args)
 
     def instant(self, name: str, *, track: str, t_us: float | None = None,
                 timeline: str | None = None, **args: Any) -> None:

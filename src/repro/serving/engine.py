@@ -419,13 +419,16 @@ class ServingEngine:
         if not self.lane_mode:
             raise RuntimeError("call enable_lane_decode() first")
         toks = np.asarray(tokens, np.int32).reshape(self.ecfg.max_batch, 1)
+        tel = self.telemetry
         t0 = time.perf_counter()
-        logits, self.cache = self._step(self.params, self.cache,
-                                        jnp.asarray(toks))
-        cur = jnp.argmax(
-            logits[:, :, : self.cfg.vocab_size], axis=-1
-        ).astype(jnp.int32)
-        nxt = np.asarray(cur).reshape(-1)
+        with tel.wall_span("decode.dispatch"):
+            logits, self.cache = self._step(self.params, self.cache,
+                                            jnp.asarray(toks))
+            cur = jnp.argmax(
+                logits[:, :, : self.cfg.vocab_size], axis=-1
+            ).astype(jnp.int32)
+        with tel.wall_span("decode.readback"):
+            nxt = np.asarray(cur).reshape(-1)
         step_us = (time.perf_counter() - t0) * 1e6
         return nxt, step_us
 
@@ -440,14 +443,15 @@ class ServingEngine:
             raise RuntimeError("call enable_lane_decode() first")
         if not lanes:
             return
-        idx = jnp.asarray(sorted(lanes))
-        cache = dict(self.cache)
-        for key, leaf in cache.items():
-            if key == "pos":
-                cache[key] = leaf.at[idx].set(0)
-            else:
-                cache[key] = leaf.at[:, idx].set(0)
-        self.cache = cache
+        with self.telemetry.wall_span("serve.reset_lanes", lanes=len(lanes)):
+            idx = jnp.asarray(sorted(lanes))
+            cache = dict(self.cache)
+            for key, leaf in cache.items():
+                if key == "pos":
+                    cache[key] = leaf.at[idx].set(0)
+                else:
+                    cache[key] = leaf.at[:, idx].set(0)
+            self.cache = cache
 
     def lane_kv_bytes(self, lanes: list[int]) -> int:
         """KV-cache bytes held live by these lanes at their current decode
@@ -493,18 +497,21 @@ class ServingEngine:
             return
         self.ensure_pool()
         idx = sorted(lanes)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
-            name = "cache" + jax.tree_util.keystr(path)
-            if name not in demoted:
-                continue
-            data = np.ascontiguousarray(np.asarray(leaf)[:, idx])
-            key = f"kv:{tenant}:{name}"
-            if key in self.pool and self.pool.nbytes(key) == data.nbytes:
-                self.pool.write(key, data)
-            else:
-                if key in self.pool:
-                    self.pool.free(key)
-                self.pool.alloc(key, data, client=tenant)
+        leaves = [("cache" + jax.tree_util.keystr(path), leaf) for path, leaf
+                  in jax.tree_util.tree_leaves_with_path(self.cache)]
+        leaves = [(name, leaf) for name, leaf in leaves if name in demoted]
+        nbytes = sum(leaf.nbytes // leaf.shape[1] * len(idx)
+                     for _name, leaf in leaves)
+        with self.telemetry.wall_span("serve.offload_kv", nbytes=nbytes):
+            for name, leaf in leaves:
+                data = np.ascontiguousarray(np.asarray(leaf)[:, idx])
+                key = f"kv:{tenant}:{name}"
+                if key in self.pool and self.pool.nbytes(key) == data.nbytes:
+                    self.pool.write(key, data)
+                else:
+                    if key in self.pool:
+                        self.pool.free(key)
+                    self.pool.alloc(key, data, client=tenant)
 
     def free_tenant_kv(self, tenant: str) -> None:
         """Drop every pool entry of this tenant's KV arena (request

@@ -304,17 +304,36 @@ class ContinuousScheduler:
         and runs the admission pass every ``readvise_every`` steps. Returns
         False when nothing is active (idle — queues empty or all shed).
         """
-        self._grant_lanes()
-        if not self._lanes:
-            return False
-        feed = np.zeros((self.engine.ecfg.max_batch,), np.int32)
-        for lane, st in self._lanes.items():
-            if st.prompt_idx < len(st.prompt):
-                feed[lane] = st.prompt[st.prompt_idx]
-            else:
-                feed[lane] = st.tokens[-1]
-        nxt, step_us = self.engine.decode_lanes(feed)
-        self._step_id += 1
+        tel = self.telemetry
+        with tel.wall_span("sched.step"):
+            with tel.wall_span("sched.grant"):
+                self._grant_lanes()
+            if not self._lanes:
+                return False
+            with tel.wall_span("sched.feed"):
+                feed = np.zeros((self.engine.ecfg.max_batch,), np.int32)
+                for lane, st in self._lanes.items():
+                    if st.prompt_idx < len(st.prompt):
+                        feed[lane] = st.prompt[st.prompt_idx]
+                    else:
+                        feed[lane] = st.tokens[-1]
+            with tel.wall_span("decode.lanes", active=len(self._lanes)):
+                nxt, step_us = self.engine.decode_lanes(feed)
+            self._step_id += 1
+            with tel.wall_span("sched.collect"):
+                retired = self._collect(nxt, step_us)
+            for lane in retired:
+                with tel.wall_span("sched.retire"):
+                    self._retire(lane)
+            if (self.scfg.readvise_every
+                    and self._step_id % self.scfg.readvise_every == 0):
+                self._admission()
+        return True
+
+    def _collect(self, nxt: np.ndarray, step_us: float) -> list[int]:
+        """Append each decoding lane's sampled token and advance prefilling
+        lanes; returns the lanes whose request is done (EOS or
+        ``max_new``)."""
         charged: set[str] = set()
         retired: list[int] = []
         for lane, st in self._lanes.items():
@@ -337,12 +356,7 @@ class ContinuousScheduler:
                 len(st.tokens) >= req.max_new
             ):
                 retired.append(lane)
-        for lane in retired:
-            self._retire(lane)
-        if (self.scfg.readvise_every
-                and self._step_id % self.scfg.readvise_every == 0):
-            self._admission()
-        return True
+        return retired
 
     def drain(self, max_steps: int = 100_000) -> int:
         """Step until every queue is empty and no lane is active.
@@ -449,6 +463,10 @@ class ContinuousScheduler:
            the real simulator; budgets are tightened if the audit misses.
         6. Admitted tenants' demoted KV is offloaded to their pool arenas.
         """
+        with self.telemetry.wall_span("sched.admission"):
+            return self._admission_pass()
+
+    def _admission_pass(self) -> dict:
         scfg, engine = self.scfg, self.engine
         ecfg = engine.ecfg
         for _tenant, ts in sorted(self.tenants.items()):
