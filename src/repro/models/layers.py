@@ -13,8 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels import kernel_backend
+from repro.kernels.decode_attention import decode_attention
 from repro.models.flash import flash_attention
-from repro.models.sharding import constrain
+from repro.models.sharding import constrain, current_mesh
 
 Params = dict[str, Any]
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
@@ -142,6 +144,46 @@ def gqa_attention(
     return out.reshape(B, S, H * Dh) @ p["wo"]
 
 
+def _decode_qkv(p: Params, x: jax.Array, pos: jax.Array, cfg: ModelConfig):
+    """q and the new token's k, v for one decode token, roped at ``pos``
+    (scalar or per-lane ``(B,)``)."""
+    B = x.shape[0]
+    H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    per_lane = jnp.ndim(pos) > 0
+    positions = jnp.reshape(pos, (B, 1)) if per_lane else jnp.full((B, 1), pos)
+    q = _split_heads(x @ p["wq"], H, Dh)
+    q = rope(q, positions, cfg.rope_theta)
+    k_new = _split_heads(x @ p["wk"], KV, Dh)
+    k_new = rope(k_new, positions, cfg.rope_theta)
+    v_new = _split_heads(x @ p["wv"], KV, Dh)
+    return q, k_new, v_new
+
+
+def _decode_slot_mask(pos: jax.Array, S_cache: int, cfg: ModelConfig):
+    """The cache slot the new token's KV lands in, and the attention mask
+    over the ``S_cache`` slots: ``(B,)`` slots and a ``(B,1,1,S)`` mask for
+    a per-lane ``pos``, a scalar slot and a ``(1,1,1,S)`` mask otherwise."""
+    idx = jnp.arange(S_cache)
+    slot = pos % S_cache if cfg.sliding_window else pos
+    if jnp.ndim(pos) > 0:
+        if cfg.sliding_window:
+            valid = (idx[None, :] <= slot[:, None]) | (pos[:, None] >= S_cache)
+        else:
+            valid = idx[None, :] <= pos[:, None]
+        return slot, valid[:, None, None, :]
+    if cfg.sliding_window:
+        valid = (idx <= slot) | (pos >= S_cache)  # ring: all valid once wrapped
+    else:
+        valid = idx <= pos
+    return slot, valid[None, None, None, :]
+
+
+def _decode_attend(p: Params, q, cache_k, cache_v, mask, cfg: ModelConfig):
+    B, _, H, Dh = q.shape
+    out = _sdpa(q, cache_k, cache_v, mask, cfg)
+    return out.reshape(B, 1, H * Dh) @ p["wo"]
+
+
 def gqa_decode_step(
     p: Params,
     x: jax.Array,
@@ -161,49 +203,75 @@ def gqa_decode_step(
     lane's arithmetic is independent of the others, so results are
     bit-identical to running that lane alone at the same batch shape.
     """
-    B = x.shape[0]
-    H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    S_cache = cache_k.shape[1]
-    per_lane = jnp.ndim(pos) > 0
-    positions = jnp.reshape(pos, (B, 1)) if per_lane else jnp.full((B, 1), pos)
-    q = _split_heads(x @ p["wq"], H, Dh)
-    q = rope(q, positions, cfg.rope_theta)
-    k_new = _split_heads(x @ p["wk"], KV, Dh)
-    k_new = rope(k_new, positions, cfg.rope_theta)
-    v_new = _split_heads(x @ p["wv"], KV, Dh)
-
-    idx = jnp.arange(S_cache)
-    if per_lane:
-        lane_pos = positions[:, 0]
-        slot = lane_pos % S_cache if cfg.sliding_window else lane_pos
-        lanes = jnp.arange(B)
+    q, k_new, v_new = _decode_qkv(p, x, pos, cfg)
+    slot, mask = _decode_slot_mask(pos, cache_k.shape[1], cfg)
+    if jnp.ndim(pos) > 0:
+        lanes = jnp.arange(x.shape[0])
         cache_k = cache_k.at[lanes, slot].set(k_new[:, 0])
         cache_v = cache_v.at[lanes, slot].set(v_new[:, 0])
-        if cfg.sliding_window:
-            valid = (idx[None, :] <= slot[:, None]) | (
-                lane_pos[:, None] >= S_cache
-            )
-        else:
-            valid = idx[None, :] <= lane_pos[:, None]
-        mask = valid[:, None, None, :]
     else:
-        slot = pos % S_cache if cfg.sliding_window else pos
         cache_k = jax.lax.dynamic_update_slice_in_dim(
             cache_k, k_new, slot, axis=1
         )
         cache_v = jax.lax.dynamic_update_slice_in_dim(
             cache_v, v_new, slot, axis=1
         )
-        if cfg.sliding_window:
-            valid = (idx <= slot) | (pos >= S_cache)  # ring: all valid once wrapped
-        else:
-            valid = idx <= pos
-        mask = valid[None, None, None, :]
     cache_k = constrain(cache_k, "batch", "kv_len", "kv_heads", None)
     cache_v = constrain(cache_v, "batch", "kv_len", "kv_heads", None)
+    return _decode_attend(p, q, cache_k, cache_v, mask, cfg), cache_k, cache_v
 
-    out = _sdpa(q, cache_k, cache_v, mask, cfg)
-    return out.reshape(B, 1, H * Dh) @ p["wo"], cache_k, cache_v
+
+def gqa_decode_step_stacked(
+    p: Params,
+    x: jax.Array,
+    k_stack: jax.Array,
+    v_stack: jax.Array,
+    layer: jax.Array,
+    pos: jax.Array,
+    cfg: ModelConfig,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`gqa_decode_step` on layer ``layer`` of the stacked caches
+    ``(L,B,S_cache,KV,Dh)``, with the same slot and mask rules.
+
+    Only the new token is written, at ``[layer, lane, slot]``, and attention
+    reads layer ``layer`` straight from the stack: a layer loop that carries
+    the stacks updates them in place instead of slicing each layer's cache
+    out and stacking it back. Compiled on a TPU, attention is the
+    :func:`~repro.kernels.decode_attention.decode_attention` kernel, which
+    DMAs the layer's blocks from the stack (its scores stay in float32);
+    elsewhere it is the jnp attention of :func:`gqa_decode_step` on
+    ``k_stack[layer]``, the same arithmetic bit for bit, which XLA on a TPU
+    would first copy out of the stack.
+    """
+    S_cache = k_stack.shape[2]
+    q, k_new, v_new = _decode_qkv(p, x, pos, cfg)
+    slot, mask = _decode_slot_mask(pos, S_cache, cfg)
+    if jnp.ndim(pos) > 0:
+        lanes = jnp.arange(x.shape[0])
+        k_stack = k_stack.at[layer, lanes, slot].set(k_new[:, 0])
+        v_stack = v_stack.at[layer, lanes, slot].set(v_new[:, 0])
+    else:
+        at = (layer, 0, slot, 0, 0)
+        k_stack = jax.lax.dynamic_update_slice(k_stack, k_new[None], at)
+        v_stack = jax.lax.dynamic_update_slice(v_stack, v_new[None], at)
+    k_stack = constrain(k_stack, "layers", "batch", "kv_len", "kv_heads", None)
+    v_stack = constrain(v_stack, "layers", "batch", "kv_len", "kv_heads", None)
+    if _decode_kernel_applies(k_stack):
+        B, _, H, Dh = q.shape
+        # valid slots: [0, pos] for a full cache, the whole ring once wrapped
+        n_valid = jnp.broadcast_to(jnp.minimum(pos + 1, S_cache), (B,))
+        out = decode_attention(q[:, 0], k_stack, v_stack, layer, n_valid)
+        return out.reshape(B, 1, H * Dh) @ p["wo"], k_stack, v_stack
+    out = _decode_attend(p, q, k_stack[layer], v_stack[layer], mask, cfg)
+    return out, k_stack, v_stack
+
+
+def _decode_kernel_applies(k_stack: jax.Array) -> bool:
+    """The decode-attention kernel runs compiled on a TPU, on an unsharded
+    stack with lane-aligned heads and whole (16, 128) tiles of positions."""
+    S_cache, Dh = k_stack.shape[2], k_stack.shape[4]
+    return (kernel_backend() == "pallas" and current_mesh() is None
+            and Dh % 128 == 0 and S_cache % 16 == 0)
 
 
 # -- SwiGLU MLP -----------------------------------------------------------

@@ -409,27 +409,25 @@ def decode_step(
     routing = None
 
     if cfg.family in ("dense", "vlm", "moe"):
+        def ffn(p, xx):
+            """The layer's MLP or MoE block; the router decision, if asked."""
+            if "moe" not in p:
+                return xx + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], xx)), None
+            h2 = L.rmsnorm(p["ln2"], xx)
+            if return_routing:
+                out, _, rt = MOE.moe_ffn(
+                    p["moe"], h2, cfg, groups=moe_groups, return_routing=True,
+                )
+                return xx + out, rt
+            out, _ = MOE.moe_ffn(p["moe"], h2, cfg, groups=moe_groups)
+            return xx + out, None
+
         if cfg.attention == "mla":
             def body(xx, scanned):
                 p, c_l, kr_l = scanned
                 h = L.rmsnorm(p["ln1"], xx)
                 o, c_l, kr_l = MLA.mla_decode_step(p["attn"], h, c_l, kr_l, pos, cfg)
-                xx = xx + o
-                rt = None
-                if "moe" in p:
-                    h2 = L.rmsnorm(p["ln2"], xx)
-                    if return_routing:
-                        out, _, rt = MOE.moe_ffn(
-                            p["moe"], h2, cfg, groups=moe_groups,
-                            return_routing=True,
-                        )
-                    else:
-                        out, _ = MOE.moe_ffn(
-                            p["moe"], h2, cfg, groups=moe_groups
-                        )
-                    xx = xx + out
-                else:
-                    xx = xx + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], xx))
+                xx, rt = ffn(p, xx + o)
                 return xx, (c_l, kr_l, rt)
 
             if cfg.first_k_dense and "dense_layers" in params:
@@ -450,30 +448,21 @@ def decode_step(
             if rt_m is not None:
                 routing = {"top_i": rt_m[0], "top_p": rt_m[1]}
         else:
-            def body(xx, scanned):
-                p, k_l, v_l = scanned
+            # the K/V stacks ride in the carry and each layer writes only its
+            # new token into them: no per-layer slice out and stack back
+            def body(carry, scanned):
+                xx, k_all, v_all = carry
+                p, layer = scanned
                 h = L.rmsnorm(p["ln1"], xx)
-                o, k_l, v_l = L.gqa_decode_step(p["attn"], h, k_l, v_l, pos, cfg)
-                xx = xx + o
-                rt = None
-                if "moe" in p:
-                    h2 = L.rmsnorm(p["ln2"], xx)
-                    if return_routing:
-                        out, _, rt = MOE.moe_ffn(
-                            p["moe"], h2, cfg, groups=moe_groups,
-                            return_routing=True,
-                        )
-                    else:
-                        out, _ = MOE.moe_ffn(
-                            p["moe"], h2, cfg, groups=moe_groups
-                        )
-                    xx = xx + out
-                else:
-                    xx = xx + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], xx))
-                return xx, (k_l, v_l, rt)
+                o, k_all, v_all = L.gqa_decode_step_stacked(
+                    p["attn"], h, k_all, v_all, layer, pos, cfg
+                )
+                xx, rt = ffn(p, xx + o)
+                return (xx, k_all, v_all), rt
 
-            x, (new_k, new_v, rt_m) = jax.lax.scan(
-                body, x, (params["layers"], cache["k"], cache["v"])
+            layer_ids = jnp.arange(cache["k"].shape[0])
+            (x, new_k, new_v), rt_m = jax.lax.scan(
+                body, (x, cache["k"], cache["v"]), (params["layers"], layer_ids)
             )
             cache = {**cache, "k": new_k, "v": new_v, "pos": pos + 1}
             if rt_m is not None:

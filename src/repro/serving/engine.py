@@ -196,10 +196,13 @@ class ServingEngine:
         self.catalog = self._build_catalog()
         self.placement = self._decide_cache_placement()
         self._offload_overflow(initial=True)
+        # the step consumes the cache it is given (every caller rebinds its
+        # cache to the step's output), so XLA updates the K/V stacks in place
         self._step = jax.jit(
             lambda params, cache, tok: self.model.decode_step(
                 params, cache, tok, self.cfg, moe_groups=1
-            )
+            ),
+            donate_argnums=(1,),
         )
         if engine_cfg.expert_paging is not None:
             self.expert_store = ExpertParamStore(
@@ -415,6 +418,11 @@ class ServingEngine:
         decode, anything for free lanes (their output is discarded — each
         lane's arithmetic is independent of the others). Returns the greedy
         next token per lane and the wall-clock step latency in us.
+
+        The step consumes the engine's cache: its arrays are donated and
+        the K/V stacks are updated in place, so the pre-step ``cache``
+        arrays are deleted once it is dispatched. No caller may hold them
+        across the call; read ``self.cache`` afresh.
         """
         if not self.lane_mode:
             raise RuntimeError("call enable_lane_decode() first")
@@ -713,7 +721,8 @@ class ServingEngine:
 
     def _decode(self, cache: Any, tok: Any) -> tuple[jax.Array, Any]:
         """One batched decode step — paged fixpoint when experts are tiered,
-        the plain jitted step otherwise."""
+        the plain jitted step otherwise. The plain step consumes ``cache``;
+        the fixpoint re-runs its step on ``cache`` and does not."""
         if self.expert_store is None:
             return self._step(self.params, cache, tok)
         return self._paged_step(cache, tok)

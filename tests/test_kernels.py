@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention_tpu
 from repro.kernels.streaming_matmul import streaming_matmul
 from repro.models.ssm import ssd_reference_recurrent
@@ -57,6 +58,36 @@ class TestFlashKernel:
             got.astype(jnp.float32), want.astype(jnp.float32),
             atol=tol, rtol=tol,
         )
+
+
+class TestDecodeAttentionKernel:
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)])
+    @pytest.mark.parametrize("S,n_valid", [
+        (32, [1, 7, 32, 20]),
+        (1024, [1, 513, 1024, 300]),   # two blocks of 512 positions
+    ])
+    def test_matches_sdpa_on_the_layer(self, S, n_valid, dtype, tol):
+        """Each layer of the stacks, read in place, against the jnp
+        attention of the decode step on that layer's slice."""
+        from repro.configs import get_config, reduced_config
+        from repro.models.layers import _sdpa
+
+        L, B, KV, G, D = 2, 4, 2, 4, 128
+        ks = jax.random.split(jax.random.PRNGKey(3), 3)
+        q = jax.random.normal(ks[0], (B, KV * G, D)).astype(dtype)
+        k = jax.random.normal(ks[1], (L, B, S, KV, D)).astype(dtype)
+        v = jax.random.normal(ks[2], (L, B, S, KV, D)).astype(dtype)
+        n_valid = jnp.array(n_valid)
+        mask = (jnp.arange(S)[None, :] < n_valid[:, None])[:, None, None, :]
+        cfg = reduced_config(get_config("granite-8b"))
+        for layer in range(L):
+            got = decode_attention(q, k, v, jnp.int32(layer), n_valid,
+                                   interpret=True)
+            want = _sdpa(q[:, None], k[layer], v[layer], mask, cfg)[:, 0]
+            np.testing.assert_allclose(
+                got.astype(jnp.float32), want.astype(jnp.float32),
+                atol=tol, rtol=tol,
+            )
 
 
 class TestSSDKernel:

@@ -95,6 +95,126 @@ def test_moe_decode_matches_forward_no_drops(arch):
     assert max(errs) < 1e-3
 
 
+def _unscanned_gqa_decode_step(params, cache, tokens, cfg):
+    """The GQA decode step as a Python loop over layers, each layer's K/V
+    sliced out of the stack, updated by ``layers.gqa_decode_step`` and
+    stacked back: the oracle of the scan that updates the stacks in place.
+    Each layer is its own jitted computation, as the scan body is, so XLA
+    rounds bf16 intermediates at the same layer boundaries."""
+    from repro.models import layers as L
+    from repro.models import moe as MOE
+
+    @jax.jit
+    def layer_step(p, x, k_l, v_l, pos):
+        o, k_l, v_l = L.gqa_decode_step(p["attn"], L.rmsnorm(p["ln1"], x),
+                                        k_l, v_l, pos, cfg)
+        x = x + o
+        h = L.rmsnorm(p["ln2"], x)
+        x = x + (MOE.moe_ffn(p["moe"], h, cfg, groups=1)[0] if "moe" in p
+                 else L.mlp(p["mlp"], h))
+        return x, k_l, v_l
+
+    pos = cache["pos"]
+    x = L.embed(params["embed"], tokens, cfg)
+    ks, vs = [], []
+    for layer in range(cfg.n_layers):
+        p = jax.tree.map(lambda t, i=layer: t[i], params["layers"])
+        x, k_l, v_l = layer_step(p, x, cache["k"][layer], cache["v"][layer],
+                                 pos)
+        ks.append(k_l)
+        vs.append(v_l)
+    logits = L.logits(params["embed"], L.rmsnorm(params["ln_f"], x), cfg)
+    return logits, {**cache, "k": jnp.stack(ks), "v": jnp.stack(vs),
+                    "pos": pos + 1}
+
+
+def _decode_case(arch, max_len, overrides, dtype, per_lane):
+    """A reduced model, a random 3-lane cache (lanes at different positions
+    if ``per_lane``), 20 steps' token feeds, the jitted decode step and the
+    jitted unscanned oracle."""
+    cfg = reduced_config(get_config(arch), dtype=dtype, **overrides)
+    model = get_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0), cfg)
+    lanes = 3
+    cache = model.init_decode_cache(cfg, lanes, max_len)
+    kk, kv, kt = jax.random.split(jax.random.PRNGKey(1), 3)
+    cache["k"] = jax.random.normal(kk, cache["k"].shape).astype(dtype)
+    cache["v"] = jax.random.normal(kv, cache["v"].shape).astype(dtype)
+    if per_lane:
+        cache["pos"] = jnp.array([0, 3, 9], jnp.int32)
+    tokens = jax.random.randint(kt, (20, lanes, 1), 0, cfg.vocab_size)
+    step = jax.jit(lambda p, c, t: model.decode_step(p, c, t, cfg,
+                                                     moe_groups=1))
+    oracle = jax.jit(lambda p, c, t: _unscanned_gqa_decode_step(p, c, t, cfg))
+    return cfg, params, cache, tokens, step, oracle
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("arch,max_len,overrides", [
+    ("granite-8b", 32, {}),
+    # a 16-slot ring, decoded past its width so it wraps
+    ("mixtral-8x7b", 16, {"sliding_window": 16}),
+])
+@pytest.mark.parametrize("per_lane", [False, True], ids=["scalar", "lanes"])
+def test_gqa_decode_step_in_place_matches_unscanned(per_lane, arch, max_len,
+                                                    overrides, dtype):
+    """The scan that carries the K/V stacks and writes each layer's new
+    token in place gives the logits and caches of slicing every layer out
+    and stacking it back, bit for bit."""
+    cfg, params, cache, tokens, step, oracle = _decode_case(
+        arch, max_len, overrides, dtype, per_lane)
+    got, want = cache, cache
+    for t in range(len(tokens)):
+        lg, got = step(params, got, tokens[t])
+        lg_want, want = oracle(params, want, tokens[t])
+        np.testing.assert_array_equal(np.asarray(lg), np.asarray(lg_want))
+        for key in ("k", "v", "pos"):
+            np.testing.assert_array_equal(np.asarray(got[key]),
+                                          np.asarray(want[key]))
+    if cfg.sliding_window:   # every lane went round the ring
+        assert int(np.min(np.asarray(got["pos"]))) > got["k"].shape[2]
+
+
+@pytest.mark.parametrize("arch,max_len,overrides,dtype,tol", [
+    ("granite-8b", 32, {}, jnp.float32, 2e-5),
+    ("granite-8b", 32, {}, jnp.bfloat16, 3e-2),
+    ("mixtral-8x7b", 16, {"sliding_window": 16}, jnp.float32, 2e-5),  # ring
+], ids=["granite-f32", "granite-bf16", "mixtral-ring-f32"])
+@pytest.mark.parametrize("per_lane", [False, True], ids=["scalar", "lanes"])
+def test_gqa_decode_step_kernel_matches_unscanned(monkeypatch, per_lane, arch,
+                                                  max_len, overrides, dtype,
+                                                  tol):
+    """The branch a TPU takes, the decode-attention kernel reading each
+    layer from the carried stacks (here interpreted), against the unscanned
+    oracle at the kernel's tolerances, relative to each array's largest
+    magnitude (a bf16 logit near 20 has a spacing of 0.125): each step
+    starts from the oracle's cache, so the gap is one step's and does not
+    compound. The MoE ring runs in f32 only: in bf16 a one-ulp attention
+    difference can flip the router's top-k, and through expert capacity
+    another lane's output, a discrete change that no tolerance bounds."""
+    from repro.models import layers as L
+    monkeypatch.setattr(L, "_decode_kernel_applies", lambda k_stack: True)
+    cfg, params, cache, tokens, step, oracle = _decode_case(
+        arch, max_len, overrides, dtype, per_lane)
+
+    def close(got, want):
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+
+    want = cache
+    for t in range(len(tokens)):
+        lg, got = step(params, want, tokens[t])
+        lg_want, want = oracle(params, want, tokens[t])
+        close(lg[..., :cfg.vocab_size], lg_want[..., :cfg.vocab_size])
+        close(got["k"], want["k"])
+        close(got["v"], want["v"])
+        np.testing.assert_array_equal(np.asarray(got["pos"]),
+                                      np.asarray(want["pos"]))
+    if cfg.sliding_window:   # every lane went round the ring
+        assert int(np.min(np.asarray(want["pos"]))) > want["k"].shape[2]
+
+
 def test_long_500k_applicability():
     subq = {a for a in ARCH_IDS if "long_500k" in runnable_cells(get_config(a))}
     assert subq == {"mixtral-8x7b", "mamba2-130m", "zamba2-1.2b"}

@@ -1,5 +1,7 @@
 """Serving engine: batched generation, determinism, DOLMA cache placement,
 and the output-equivalence battery (tiered + pooled == untiered, bit-exact)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -171,3 +173,53 @@ def test_placement_summary_records_offload_capability(engine_setup):
     # no demotions -> nothing to offload, whatever the backend supports
     roomy = ServingEngine(cfg, params, EngineConfig(max_batch=2, max_len=64))
     assert roomy.stats()["placement"]["offload_memory_kind"] is None
+
+
+# -- the lane step updates its cache in place ---------------------------------
+def _compiled_lane_step(cfg, params, lanes=3, max_len=32):
+    eng = ServingEngine(cfg, params,
+                        EngineConfig(max_batch=lanes, max_len=max_len))
+    eng.enable_lane_decode()
+    tok = jnp.zeros((lanes, 1), jnp.int32)
+    return eng, eng._step.lower(params, eng.cache, tok).compile().as_text()
+
+
+def test_lane_step_donates_its_cache(engine_setup):
+    """The lane step aliases the K/V stacks to its output and consumes the
+    cache it is given; ``generate()``, on the same step, leaves the engine a
+    live cache to decode from again."""
+    cfg, _model, params = engine_setup
+    eng, text = _compiled_lane_step(cfg, params)
+    (aliases,) = re.findall(r"input_output_alias=\{(.*?) \}, ", text)
+    aliased = {int(i) for i in re.findall(r"\((\d+), \{\}, may-alias\)",
+                                          aliases)}
+    n_params = len(jax.tree.leaves(params))
+    keys = [jax.tree_util.keystr(path) for path, _ in
+            jax.tree_util.tree_flatten_with_path(eng.cache)[0]]
+    assert {n_params + keys.index("['k']"),
+            n_params + keys.index("['v']")} <= aliased
+
+    before = eng.cache
+    eng.decode_lanes(np.array([3, 5, 7]))
+    assert all(leaf.is_deleted() for leaf in before.values())
+    assert not any(leaf.is_deleted() for leaf in eng.cache.values())
+
+    wave = ServingEngine(cfg, params, EngineConfig(max_batch=2, max_len=32))
+    prompts = np.array([[5, 9, 2], [7, 1, 3]], np.int32)
+    first = wave.generate(prompts, max_new=2)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(wave.cache))
+    wave.reset()
+    np.testing.assert_array_equal(wave.generate(prompts, max_new=2), first)
+
+
+def test_lane_step_writes_no_whole_layer_of_kv(engine_setup):
+    """The compiled lane step writes the new token into the K/V stacks, not a
+    whole layer's ``(1, B, S, KV, Dh)`` slice stacked back per layer."""
+    cfg, _model, params = engine_setup
+    lanes, max_len = 3, 32
+    _eng, text = _compiled_lane_step(cfg, params, lanes, max_len)
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    updates = [shape_of[m] for m in re.findall(
+        r"dynamic-update-slice\(%[\w.\-]+, %([\w.\-]+)", text)]
+    whole_layer = f"f32[1,{lanes},{max_len},{cfg.n_kv_heads},{cfg.head_dim}]"
+    assert whole_layer not in updates

@@ -116,3 +116,29 @@ def test_granite_decode_step_fits_hbm(shape_on):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES
+
+
+def test_granite_lane_step_reads_kv_stacks_in_place(shape_on, monkeypatch):
+    """The donating lane step at granite-8b widths, 2 layers, 8 lanes, 2048
+    context: attention is the decode-attention kernel, the caches alias the
+    output, and no layer of K or V is copied out of the stacks."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    cfg = dataclasses.replace(get_config("granite-8b"), n_layers=2)
+    model = get_model(cfg)
+    place = functools.partial(jax.tree.map,
+                              lambda s: shape_on(s.shape, s.dtype))
+    params = place(jax.eval_shape(
+        functools.partial(model.init_params, cfg=cfg), jax.random.PRNGKey(0)))
+    cache = dict(jax.eval_shape(
+        functools.partial(model.init_decode_cache, cfg, 8, 2048)))
+    cache["pos"] = jax.ShapeDtypeStruct((8,), jnp.int32)
+    cache = place(cache)
+    step = jax.jit(lambda p, c, t: model.decode_step(p, c, t, cfg,
+                                                     moe_groups=1),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, cache, shape_on((8, 1), jnp.int32)).compile()
+    assert _has_kernel(compiled)
+    mem = compiled.memory_analysis()
+    k_bytes = cache["k"].size * cache["k"].dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * k_bytes          # K and V
+    assert mem.temp_size_in_bytes < k_bytes // cfg.n_layers  # one layer's K
