@@ -48,7 +48,13 @@ def reduced_config(config: ModelConfig, **overrides) -> ModelConfig:
         small.update(n_heads=4, n_kv_heads=min(config.n_kv_heads, 4) or 1, head_dim=16)
     if config.is_moe:
         small.update(n_experts=4, top_k=min(config.top_k, 2), moe_d_ff=32,
-                     first_k_dense=min(config.first_k_dense, 1))
+                     first_k_dense=min(config.first_k_dense, 1),
+                     n_experts_held=0, expert_shard=0)
+        if config.n_group > 1:
+            # two groups of two experts, the published share of them kept
+            n_group = 2
+            small.update(n_group=n_group, topk_group=max(
+                1, n_group * config.topk_group // config.n_group))
     if config.attention == "mla":
         small.update(q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
                      qk_rope_head_dim=8, v_head_dim=16, head_dim=24)

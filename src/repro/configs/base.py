@@ -25,6 +25,13 @@ class ModelConfig:
     attention: Literal["gqa", "mla", "none"] = "gqa"
     sliding_window: int | None = None  # SWA width (mixtral: 4096)
     rope_theta: float = 1e4
+    # YaRN context extension (DeepSeek-V3); rope_factor 0 is plain RoPE
+    rope_factor: float = 0.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # MoE
     n_experts: int = 0
@@ -34,6 +41,18 @@ class ModelConfig:
     first_k_dense: int = 0      # deepseek: first 3 layers dense
     capacity_factor: float = 1.25
     expert_sharding: Literal["expert", "tensor"] = "expert"
+    # the router: softmax top-k (mixtral), or DeepSeek-V3's sigmoid scores
+    # with a choice-only bias, chosen inside the topk_group best of n_group
+    # groups, normalised and scaled by routed_scaling_factor
+    scoring_func: Literal["softmax", "sigmoid"] = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # the chip's share under expert parallelism: experts
+    # [expert_shard * n_experts_held, (expert_shard + 1) * n_experts_held)
+    # of the n_experts the router chooses from; 0 holds all of them
+    n_experts_held: int = 0
+    expert_shard: int = 0
 
     # MLA (deepseek-v3 dims)
     q_lora_rank: int = 0
@@ -63,7 +82,8 @@ class ModelConfig:
     mtp_depth: int = 0
 
     dtype: Any = jnp.bfloat16
-    tie_embeddings: bool = False
+    # the output head reuses the input embedding; False gives it its own
+    tie_embeddings: bool = True
 
     def __post_init__(self):
         if self.head_dim == 0 and self.n_heads:
@@ -73,6 +93,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def n_experts_local(self) -> int:
+        """Routed experts whose weights this program holds."""
+        return self.n_experts_held or self.n_experts
 
     @property
     def is_ssm(self) -> bool:
@@ -132,7 +157,9 @@ class ModelConfig:
             # mlp / moe
             if self.is_moe and layer >= self.first_k_dense:
                 n += d * self.n_experts  # router
-                n += self.n_experts * 3 * d * self.moe_d_ff
+                if self.scoring_func == "sigmoid":
+                    n += self.n_experts  # the choice-only correction bias
+                n += self.n_experts_local * 3 * d * self.moe_d_ff
                 n += self.n_shared_experts * 3 * d * self.moe_d_ff
             else:
                 n += 3 * d * ff
@@ -154,7 +181,8 @@ class ModelConfig:
             return self.param_count()
         total = self.param_count()
         n_moe_layers = self.n_layers - self.first_k_dense
-        all_expert = n_moe_layers * self.n_experts * 3 * self.d_model * self.moe_d_ff
+        all_expert = (n_moe_layers * self.n_experts_local * 3 * self.d_model
+                      * self.moe_d_ff)
         active_expert = n_moe_layers * self.top_k * 3 * self.d_model * self.moe_d_ff
         return total - all_expert + active_expert
 

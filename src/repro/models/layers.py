@@ -6,6 +6,7 @@ jit/scan/vmap-safe. Tensors are annotated with logical axis names resolved by
 """
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -42,14 +43,55 @@ def rmsnorm(p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 # -- rotary ------------------------------------------------------------------
 
-def rope(x: jax.Array, positions: jax.Array, theta: float = 1e4) -> jax.Array:
-    """x: (..., S, H, D) with positions (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """The ``dim // 2`` rotary frequencies: ``theta^(-2i/dim)``, or with
+    ``cfg.rope_factor`` YaRN's blend of them with the interpolated
+    ``theta^(-2i/dim) / factor``. Dimensions below ``low`` keep their
+    frequency, those above ``high`` are interpolated, and a linear ramp
+    joins them; ``low``/``high`` are where ``beta_fast``/``beta_slow``
+    rotations fit into the original context."""
+    theta = cfg.rope_theta
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not cfg.rope_factor:
+        return extra.astype(np.float32)
+
+    def at(rotations):
+        return dim * math.log(cfg.rope_original_max_position
+                              / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(at(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(at(cfg.rope_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    inv = extra / cfg.rope_factor * ramp + extra * (1 - ramp)
+    return inv.astype(np.float32)
+
+
+def rope_cos_scale(cfg: ModelConfig) -> float:
+    """YaRN's factor on cos and sin (1 without YaRN)."""
+    if not cfg.rope_factor:
+        return 1.0
+    return (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+            / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float = 1e4,
+         inv_freq: np.ndarray | None = None, cos_scale: float = 1.0) -> jax.Array:
+    """x: (..., S, H, D) with positions (..., S); the two halves of D are
+    rotated by ``inv_freq`` (default ``theta^(-2i/D)``)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = (jnp.asarray(inv_freq) if inv_freq is not None else
+             1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)))
     # positions: (..., S) -> (..., S, 1, 1) broadcast against (half,)
     angles = positions.astype(jnp.float32)[..., None, None] * freqs  # (...,S,1,half)
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if cos_scale != 1.0:
+        sin, cos = sin * cos_scale, cos * cos_scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [
@@ -301,6 +343,9 @@ def padded_vocab(cfg: ModelConfig, multiple: int = 2048) -> int:
 def embed_init(key, cfg: ModelConfig) -> Params:
     V = padded_vocab(cfg)
     p = {"embedding": _init(key, (V, cfg.d_model), cfg.dtype, scale=1.0)}
+    if not cfg.tie_embeddings:
+        p["head"] = _init(jax.random.fold_in(key, 1), (V, cfg.d_model),
+                          cfg.dtype, scale=1.0 / np.sqrt(cfg.d_model))
     return p
 
 
@@ -312,8 +357,10 @@ def embed(p: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def logits(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """x: (B,S,d) -> (B,S,V_padded), vocab-sharded; padded region masked."""
-    e = p["embedding"]
+    """x: (B,S,d) -> (B,S,V_padded), vocab-sharded; padded region masked.
+    The head is ``p["head"]`` where the model has one of its own, else the
+    input embedding."""
+    e = p.get("head", p["embedding"])
     out = (x @ e.T.astype(x.dtype)).astype(jnp.float32)
     V = padded_vocab(cfg)
     if V != cfg.vocab_size:

@@ -13,7 +13,8 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.flash import flash_attention
-from repro.models.layers import NEG_INF, Params, _init, rmsnorm, rope
+from repro.models.layers import (NEG_INF, Params, _init, rmsnorm, rope,
+                                 rope_cos_scale, rope_inv_freq, yarn_mscale)
 from repro.models.sharding import constrain
 
 
@@ -33,6 +34,21 @@ def mla_init(key, cfg: ModelConfig) -> Params:
     }
 
 
+def softmax_scale(cfg: ModelConfig) -> float:
+    """``1 / sqrt(qk head dim)``, times YaRN's ``mscale(factor,
+    mscale_all_dim)^2`` where the model extends its context by YaRN."""
+    scale = 1.0 / np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if cfg.rope_factor and cfg.rope_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+    return float(scale)
+
+
+def _rope(x, positions, cfg):
+    return rope(x, positions, cfg.rope_theta,
+                inv_freq=rope_inv_freq(cfg, cfg.qk_rope_head_dim),
+                cos_scale=rope_cos_scale(cfg))
+
+
 def _project_q(p, x, cfg, positions):
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -40,7 +56,7 @@ def _project_q(p, x, cfg, positions):
     cq = rmsnorm(p["q_ln"], x @ p["wq_a"])
     q = (cq @ p["wq_b"]).reshape(B, S, H, nope + rdim)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _rope(q_rope, positions, cfg)
     return q_nope, q_rope
 
 
@@ -48,7 +64,7 @@ def _project_kv_latent(p, x, cfg, positions):
     kr, rdim = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     ckv = x @ p["wkv_a"]  # (B,S,kr+rdim)
     c_kv = rmsnorm(p["kv_ln"], ckv[..., :kr])
-    k_rope = rope(ckv[..., None, kr:], positions, cfg.rope_theta)[:, :, 0]  # (B,S,rdim)
+    k_rope = _rope(ckv[..., None, kr:], positions, cfg)[:, :, 0]  # (B,S,rdim)
     return c_kv, k_rope
 
 
@@ -72,7 +88,7 @@ def mla_attention(
     q = constrain(q, "batch", None, "heads", None)
     k = constrain(k, "batch", None, "heads", None)
     v = constrain(v, "batch", None, "heads", None)
-    out = flash_attention(q, k, v, causal=True, scale=1.0 / np.sqrt(nope + rdim))
+    out = flash_attention(q, k, v, causal=True, scale=softmax_scale(cfg))
     out = constrain(out, "batch", None, "heads", None)
     return out.reshape(B, S, H * vh) @ p["wo"]
 
@@ -80,22 +96,27 @@ def mla_attention(
 def mla_decode_step(
     p: Params,
     x: jax.Array,
-    cache_c: jax.Array,   # (B, S_max, kv_lora_rank)
-    cache_kr: jax.Array,  # (B, S_max, qk_rope_head_dim)
+    c_stack: jax.Array,   # (L, B, S_max, kv_lora_rank)
+    kr_stack: jax.Array,  # (L, B, S_max, qk_rope_head_dim)
+    layer: jax.Array,
     pos: jax.Array,
     cfg: ModelConfig,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Absorbed one-token decode against the compressed-latent cache.
+    """Absorbed one-token decode against layer ``layer`` of the stacked
+    compressed-latent caches.
 
     ``pos`` is a scalar (whole batch at one position) or a per-lane ``(B,)``
     vector (continuous batching: each lane's latent lands at its own slot
-    and is masked to its own prefix).
+    and is masked to its own prefix). Only the new latent is written, at
+    ``[layer, lane, pos]``, and the scores and context read layer ``layer``
+    straight from the stacks: a layer loop that carries the stacks updates
+    them in place instead of slicing each layer out and stacking it back.
     """
     B = x.shape[0]
     H = cfg.n_heads
-    nope, rdim, vh = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    nope, vh = cfg.qk_nope_head_dim, cfg.v_head_dim
     kr = cfg.kv_lora_rank
-    S_max = cache_c.shape[1]
+    S_max = c_stack.shape[2]
 
     per_lane = jnp.ndim(pos) > 0
     positions = (jnp.reshape(pos, (B, 1)) if per_lane
@@ -104,33 +125,32 @@ def mla_decode_step(
     c_new, kr_new = _project_kv_latent(p, x, cfg, positions)
     if per_lane:
         lanes = jnp.arange(B)
-        cache_c = cache_c.at[lanes, positions[:, 0]].set(c_new[:, 0])
-        cache_kr = cache_kr.at[lanes, positions[:, 0]].set(kr_new[:, 0])
+        c_stack = c_stack.at[layer, lanes, positions[:, 0]].set(c_new[:, 0])
+        kr_stack = kr_stack.at[layer, lanes, positions[:, 0]].set(kr_new[:, 0])
     else:
-        cache_c = jax.lax.dynamic_update_slice_in_dim(cache_c, c_new, pos, axis=1)
-        cache_kr = jax.lax.dynamic_update_slice_in_dim(cache_kr, kr_new, pos, axis=1)
-    cache_c = constrain(cache_c, "batch", "kv_len", None)
-    cache_kr = constrain(cache_kr, "batch", "kv_len", None)
+        at = (layer, 0, pos, 0)
+        c_stack = jax.lax.dynamic_update_slice(c_stack, c_new[None], at)
+        kr_stack = jax.lax.dynamic_update_slice(kr_stack, kr_new[None], at)
+    c_stack = constrain(c_stack, "layers", "batch", "kv_len", None)
+    kr_stack = constrain(kr_stack, "layers", "batch", "kv_len", None)
+    cache_c, cache_kr = c_stack[layer], kr_stack[layer]
 
     wkv_b = p["wkv_b"].reshape(kr, H, nope + vh)
     w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
 
     # absorb W_uk into q: score directly against the latent cache
     q_c = jnp.einsum("bqhn,lhn->bqhl", q_nope, w_uk)  # (B,1,H,kr)
-    scale = 1.0 / np.sqrt(nope + rdim)
+    f32 = jnp.float32
     scores = (
-        jnp.einsum("bqhl,bsl->bhqs", q_c, cache_c)
-        + jnp.einsum("bqhr,bsr->bhqs", q_rope, cache_kr)
-    ).astype(jnp.float32) * scale
-    if per_lane:
-        valid = (jnp.arange(S_max)[None, :]
-                 <= positions)[:, None, None, :]  # (B,1,1,S)
-    else:
-        valid = (jnp.arange(S_max) <= pos)[None, None, None, :]
-    scores = jnp.where(valid, scores, NEG_INF)
+        jnp.einsum("bqhl,bsl->bhqs", q_c, cache_c, preferred_element_type=f32)
+        + jnp.einsum("bqhr,bsr->bhqs", q_rope, cache_kr,
+                     preferred_element_type=f32)
+    ) * softmax_scale(cfg)
+    valid = jnp.arange(S_max)[None, :] <= positions  # (B,S)
+    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
 
     ctx = jnp.einsum("bhqs,bsl->bqhl", probs, cache_c)  # (B,1,H,kr)
     out = jnp.einsum("bqhl,lhv->bqhv", ctx, w_uv)       # (B,1,H,vh)
     out = out.reshape(B, 1, H * vh) @ p["wo"]
-    return out, cache_c, cache_kr
+    return out, c_stack, kr_stack

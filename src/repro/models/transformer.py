@@ -409,64 +409,53 @@ def decode_step(
     routing = None
 
     if cfg.family in ("dense", "vlm", "moe"):
-        def ffn(p, xx):
+        nd = cfg.first_k_dense if "dense_layers" in params else 0
+        layers = params["layers"]
+        if "moe" in layers:
+            # the routed experts stay out of the scanned slices: each MoE
+            # layer reads its own from the whole stacks, in place
+            experts = {k: layers["moe"][k] for k in MOE.EXPERT_KEYS}
+            layers = {**layers, "moe": {k: v for k, v in layers["moe"].items()
+                                        if k not in experts}}
+
+        def ffn(p, xx, layer):
             """The layer's MLP or MoE block; the router decision, if asked."""
             if "moe" not in p:
                 return xx + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], xx)), None
             h2 = L.rmsnorm(p["ln2"], xx)
-            if return_routing:
-                out, _, rt = MOE.moe_ffn(
-                    p["moe"], h2, cfg, groups=moe_groups, return_routing=True,
-                )
-                return xx + out, rt
-            out, _ = MOE.moe_ffn(p["moe"], h2, cfg, groups=moe_groups)
-            return xx + out, None
-
-        if cfg.attention == "mla":
-            def body(xx, scanned):
-                p, c_l, kr_l = scanned
-                h = L.rmsnorm(p["ln1"], xx)
-                o, c_l, kr_l = MLA.mla_decode_step(p["attn"], h, c_l, kr_l, pos, cfg)
-                xx, rt = ffn(p, xx + o)
-                return xx, (c_l, kr_l, rt)
-
-            if cfg.first_k_dense and "dense_layers" in params:
-                nd = cfg.first_k_dense
-                x, (c_d, kr_d, _) = jax.lax.scan(
-                    body, x, (params["dense_layers"], cache["c"][:nd], cache["kr"][:nd])
-                )
-                x, (c_m, kr_m, rt_m) = jax.lax.scan(
-                    body, x, (params["layers"], cache["c"][nd:], cache["kr"][nd:])
-                )
-                new_c = jnp.concatenate([c_d, c_m], 0)
-                new_kr = jnp.concatenate([kr_d, kr_m], 0)
-            else:
-                x, (new_c, new_kr, rt_m) = jax.lax.scan(
-                    body, x, (params["layers"], cache["c"], cache["kr"])
-                )
-            cache = {**cache, "c": new_c, "kr": new_kr, "pos": pos + 1}
-            if rt_m is not None:
-                routing = {"top_i": rt_m[0], "top_p": rt_m[1]}
-        else:
-            # the K/V stacks ride in the carry and each layer writes only its
-            # new token into them: no per-layer slice out and stack back
-            def body(carry, scanned):
-                xx, k_all, v_all = carry
-                p, layer = scanned
-                h = L.rmsnorm(p["ln1"], xx)
-                o, k_all, v_all = L.gqa_decode_step_stacked(
-                    p["attn"], h, k_all, v_all, layer, pos, cfg
-                )
-                xx, rt = ffn(p, xx + o)
-                return (xx, k_all, v_all), rt
-
-            layer_ids = jnp.arange(cache["k"].shape[0])
-            (x, new_k, new_v), rt_m = jax.lax.scan(
-                body, (x, cache["k"], cache["v"]), (params["layers"], layer_ids)
+            out = MOE.moe_ffn(
+                {**p["moe"], **experts}, h2, cfg, groups=moe_groups,
+                return_routing=return_routing, layer=layer - nd,
             )
-            cache = {**cache, "k": new_k, "v": new_v, "pos": pos + 1}
-            if rt_m is not None:
-                routing = {"top_i": rt_m[0], "top_p": rt_m[1]}
+            return xx + out[0], (out[2] if return_routing else None)
+
+        # the cache stacks ride in the carry and each layer writes only its
+        # new token into them: no per-layer slice out and stack back. The
+        # dense prefix and the MoE layers carry the same stacks, the MoE
+        # scan's layer ids offset by the prefix.
+        if cfg.attention == "mla":
+            keys, attend = ("c", "kr"), MLA.mla_decode_step
+        else:
+            keys, attend = ("k", "v"), L.gqa_decode_step_stacked
+
+        def body(carry, scanned):
+            xx, a_all, b_all = carry
+            p, layer = scanned
+            h = L.rmsnorm(p["ln1"], xx)
+            o, a_all, b_all = attend(p["attn"], h, a_all, b_all, layer, pos,
+                                     cfg)
+            xx, rt = ffn(p, xx + o, layer)
+            return (xx, a_all, b_all), rt
+
+        carry = (x, cache[keys[0]], cache[keys[1]])
+        if nd:
+            carry, _ = jax.lax.scan(
+                body, carry, (params["dense_layers"], jnp.arange(nd)))
+        (x, new_a, new_b), rt_m = jax.lax.scan(
+            body, carry, (layers, nd + jnp.arange(cfg.n_layers - nd)))
+        cache = {**cache, keys[0]: new_a, keys[1]: new_b, "pos": pos + 1}
+        if rt_m is not None:
+            routing = {"top_i": rt_m[0], "top_p": rt_m[1]}
 
     elif cfg.family == "ssm":
         def body(xx, scanned):

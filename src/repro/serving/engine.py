@@ -20,6 +20,7 @@ paper's ≤16% knee.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Any
@@ -46,6 +47,7 @@ from repro.core.sizing import (
 from repro.core.telemetry import NULL_TELEMETRY, Telemetry
 from repro.core.tiering import supports_host_offload
 from repro.models import get_model
+from repro.models.moe import held_counts
 from repro.serving.expert_paging import (
     ExpertPager,
     ExpertPagingConfig,
@@ -197,13 +199,26 @@ class ServingEngine:
         self.placement = self._decide_cache_placement()
         self._offload_overflow(initial=True)
         # the step consumes the cache it is given (every caller rebinds its
-        # cache to the step's output), so XLA updates the K/V stacks in place
-        self._step = jax.jit(
-            lambda params, cache, tok: self.model.decode_step(
-                params, cache, tok, self.cfg, moe_groups=1
-            ),
-            donate_argnums=(1,),
-        )
+        # cache to the step's output), so XLA updates the K/V stacks in place.
+        # On MoE models it also adds its [routed, active] held-expert counts
+        # to a pair summed on the device over steps, kept apart from the
+        # per-lane cache leaves that reset_lanes zeroes (read by moe_counts)
+        self._moe_counts = None
+        if cfg.is_moe:
+            self._moe_counts = jnp.zeros((2,), jnp.int32)
+            self._step = jax.jit(
+                lambda params, cache, tok, counts: self._counted_step(
+                    params, cache, tok, counts
+                ),
+                donate_argnums=(1, 3),
+            )
+        else:
+            self._step = jax.jit(
+                lambda params, cache, tok: self.model.decode_step(
+                    params, cache, tok, self.cfg, moe_groups=1
+                ),
+                donate_argnums=(1,),
+            )
         if engine_cfg.expert_paging is not None:
             self.expert_store = ExpertParamStore(
                 params, cfg, self.ensure_pool(),
@@ -223,6 +238,50 @@ class ServingEngine:
                     return_routing=True,
                 )
             )
+
+    @staticmethod
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def _zero_lane(cache: dict, lane: jax.Array) -> dict:
+        """``cache`` with lane ``lane``'s slices and position zeroed: one
+        lane's bytes written into the donated stacks, not a copy of them."""
+        out = {}
+        for key, leaf in cache.items():
+            axis = 0 if key == "pos" else 1
+            zero = jnp.zeros(leaf.shape[:axis] + (1,) + leaf.shape[axis + 1:],
+                             leaf.dtype)
+            out[key] = jax.lax.dynamic_update_slice_in_dim(leaf, zero, lane,
+                                                           axis)
+        return out
+
+    def _counted_step(self, params, cache, tok, counts):
+        """The decode step, adding its held-expert counts to ``counts``."""
+        logits, cache, routing = self.model.decode_step(
+            params, cache, tok, self.cfg, moe_groups=1, return_routing=True
+        )
+        return logits, cache, counts + held_counts(routing["top_i"], self.cfg)
+
+    def _run_step(self, cache: Any, tok: Any) -> tuple[jax.Array, Any]:
+        """One plain decode step on ``cache`` (consumed), counting held
+        experts on MoE models."""
+        if self._moe_counts is None:
+            return self._step(self.params, cache, tok)
+        logits, cache, self._moe_counts = self._step(
+            self.params, cache, tok, self._moe_counts)
+        return logits, cache
+
+    def moe_counts(self) -> dict[str, int]:
+        """Held-expert counts summed over every plain decode step so far:
+        ``moe_routed``, token slots routed to the experts held here (over
+        layers), and ``moe_active``, (layer, held expert) pairs with at
+        least one token. One device read; sets the ``serving.moe_routed``
+        and ``serving.moe_active`` gauges."""
+        if self._moe_counts is None:
+            raise ValueError(f"{self.cfg.name} routes no experts")
+        with self.telemetry.wall_span("moe.counts"):
+            routed, active = (int(v) for v in np.asarray(self._moe_counts))
+        self.telemetry.gauge("serving.moe_routed", routed)
+        self.telemetry.gauge("serving.moe_active", active)
+        return {"moe_routed": routed, "moe_active": active}
 
     # -- DOLMA placement over serving objects -------------------------------
     def _build_catalog(self) -> ObjectCatalog:
@@ -430,8 +489,7 @@ class ServingEngine:
         tel = self.telemetry
         t0 = time.perf_counter()
         with tel.wall_span("decode.dispatch"):
-            logits, self.cache = self._step(self.params, self.cache,
-                                            jnp.asarray(toks))
+            logits, self.cache = self._run_step(self.cache, jnp.asarray(toks))
             cur = jnp.argmax(
                 logits[:, :, : self.cfg.vocab_size], axis=-1
             ).astype(jnp.int32)
@@ -445,21 +503,16 @@ class ServingEngine:
 
         Called when a request joins (fresh context) and when it retires
         (drop its KV occupancy); other lanes are untouched, so in-flight
-        requests never observe the reset.
+        requests never observe the reset. Like :meth:`decode_lanes` it
+        consumes the cache: each lane's slices are zeroed in place.
         """
         if not self.lane_mode:
             raise RuntimeError("call enable_lane_decode() first")
         if not lanes:
             return
         with self.telemetry.wall_span("serve.reset_lanes", lanes=len(lanes)):
-            idx = jnp.asarray(sorted(lanes))
-            cache = dict(self.cache)
-            for key, leaf in cache.items():
-                if key == "pos":
-                    cache[key] = leaf.at[idx].set(0)
-                else:
-                    cache[key] = leaf.at[:, idx].set(0)
-            self.cache = cache
+            for lane in sorted(set(lanes)):
+                self.cache = self._zero_lane(self.cache, jnp.int32(lane))
 
     def lane_kv_bytes(self, lanes: list[int]) -> int:
         """KV-cache bytes held live by these lanes at their current decode
@@ -724,7 +777,7 @@ class ServingEngine:
         the plain jitted step otherwise. The plain step consumes ``cache``;
         the fixpoint re-runs its step on ``cache`` and does not."""
         if self.expert_store is None:
-            return self._step(self.params, cache, tok)
+            return self._run_step(cache, tok)
         return self._paged_step(cache, tok)
 
     def _paged_step(self, cache: Any, tok: Any) -> tuple[jax.Array, Any]:

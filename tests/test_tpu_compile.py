@@ -12,6 +12,7 @@ this file.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.kernels.moe_experts import held_experts
 from repro.kernels.flash_attention import flash_attention_tpu
 from repro.kernels.streaming_matmul import streaming_matmul
 from repro.models import get_model
@@ -142,3 +144,79 @@ def test_granite_lane_step_reads_kv_stacks_in_place(shape_on, monkeypatch):
     k_bytes = cache["k"].size * cache["k"].dtype.itemsize
     assert mem.alias_size_in_bytes >= 2 * k_bytes          # K and V
     assert mem.temp_size_in_bytes < k_bytes // cfg.n_layers  # one layer's K
+
+
+def _deepseek_cell_config():
+    """deepseek-v3 as the chat cell serves it: 7 layers (1 dense), 8 of
+    256 routed experts held, an eighth of the vocabulary, no MTP."""
+    return dataclasses.replace(get_config("deepseek-v3-671b"), n_layers=7,
+                               first_k_dense=1, vocab_size=16160,
+                               n_experts_held=8, mtp_depth=0)
+
+
+def _top_level_results(hlo: str) -> list[str]:
+    """Result shapes of the ops outside fused computations: the arrays the
+    program materializes."""
+    fused, comps, cur = set(), {}, None
+    for line in hlo.splitlines():
+        if not line.startswith(" ") and line.rstrip().endswith("{"):
+            cur = line.split()[1 if line.startswith("ENTRY") else 0]
+            comps[cur.lstrip("%")] = []
+        elif cur is not None and line.startswith(" "):
+            comps[cur.lstrip("%")].append(line)
+            if " fusion(" in line:
+                fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+    return [m.group(1) for name, lines in comps.items() if name not in fused
+            for line in lines
+            if (m := re.search(r"= (\w+\[[\d,]*\])", line))]
+
+
+def test_deepseek_lane_step_fits_and_reads_latent_in_place(shape_on,
+                                                          monkeypatch):
+    """The donating lane step at the cell's sizes (7 layers, 64 lanes, 4096
+    positions) fits one v5e, runs the held-expert kernel, and materializes
+    no whole layer of the latent stacks (every layer slice is fused into
+    the score and context dots) and no layer's held experts."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    cfg = _deepseek_cell_config()
+    model = get_model(cfg)
+    place = functools.partial(jax.tree.map,
+                              lambda s: shape_on(s.shape, s.dtype))
+    params = place(jax.eval_shape(
+        functools.partial(model.init_params, cfg=cfg), jax.random.PRNGKey(0)))
+    lanes, max_len = 64, 4096
+    cache = dict(jax.eval_shape(
+        functools.partial(model.init_decode_cache, cfg, lanes, max_len)))
+    cache["pos"] = jax.ShapeDtypeStruct((lanes,), jnp.int32)
+    cache = place(cache)
+    step = jax.jit(lambda p, c, t: model.decode_step(p, c, t, cfg,
+                                                     moe_groups=1),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, cache,
+                          shape_on((lanes, 1), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert _has_kernel(compiled)
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < used < V5E_HBM_BYTES
+    whole = {f"bf16[{one}{lanes},{max_len},{w}]" for one in ("", "1,")
+             for w in (cfg.kv_lora_rank, cfg.qk_rope_head_dim)}
+    # nor a layer's held experts, which the kernel reads from the stacks
+    experts = {f"bf16[{E},{a},{b}]" for E in (cfg.n_experts_held,)
+               for a, b in ((cfg.d_model, cfg.moe_d_ff),
+                            (cfg.moe_d_ff, cfg.d_model))}
+    assert not (whole | experts) & set(_top_level_results(hlo))
+
+
+def test_held_expert_kernel_deepseek_share(shape_on):
+    """The held-expert kernel at DeepSeek-V3's share: 8 experts, 64 tokens
+    each at most, hidden 7168, expert width 2048, from 6 layers' stacks."""
+    E, cap, d, ff = 8, 64, 7168, 2048
+    L = 6
+    fn = jax.jit(functools.partial(held_experts, interpret=False))
+    compiled = fn.lower(shape_on((E, cap, d)), shape_on((L, E, d, ff)),
+                        shape_on((L, E, d, ff)), shape_on((L, E, ff, d)),
+                        shape_on((E,), jnp.int32),
+                        shape_on((), jnp.int32)).compile()
+    assert _has_kernel(compiled)
