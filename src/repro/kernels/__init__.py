@@ -6,11 +6,12 @@ the same code validates on CPU CI hosts). :func:`kernel_backend` picks the
 right one for the current platform — compiled on TPU, interpret elsewhere.
 The ``REPRO_KERNEL_BACKEND`` environment variable (``pallas`` | ``interpret``
 | ``auto``) can force compiled Pallas anywhere, and the interpreter only off
-a TPU: on a TPU it raises rather than hide the device. All five kernels
+a TPU: on a TPU it raises rather than hide the device. All six kernels
 (``streaming_matmul``, ``flash_attention``, ``ssd_scan``,
-``decode_attention``, ``moe_experts``) and the tests resolve their
-``interpret=None`` default through :func:`resolve_interpret`, so there is
-exactly one place where the platform decision lives.
+``decode_attention``, ``mla_decode_attention``, ``moe_experts``) and the
+tests resolve their ``interpret=None`` default through
+:func:`resolve_interpret`, so there is exactly one place where the
+platform decision lives.
 """
 from __future__ import annotations
 

@@ -298,7 +298,7 @@ def gqa_decode_step_stacked(
         v_stack = jax.lax.dynamic_update_slice(v_stack, v_new[None], at)
     k_stack = constrain(k_stack, "layers", "batch", "kv_len", "kv_heads", None)
     v_stack = constrain(v_stack, "layers", "batch", "kv_len", "kv_heads", None)
-    if _decode_kernel_applies(k_stack):
+    if decode_kernel_applies(k_stack):
         B, _, H, Dh = q.shape
         # valid slots: [0, pos] for a full cache, the whole ring once wrapped
         n_valid = jnp.broadcast_to(jnp.minimum(pos + 1, S_cache), (B,))
@@ -308,12 +308,15 @@ def gqa_decode_step_stacked(
     return out, k_stack, v_stack
 
 
-def _decode_kernel_applies(k_stack: jax.Array) -> bool:
-    """The decode-attention kernel runs compiled on a TPU, on an unsharded
-    stack with lane-aligned heads and whole (16, 128) tiles of positions."""
-    S_cache, Dh = k_stack.shape[2], k_stack.shape[4]
+def decode_kernel_applies(stack: jax.Array, positions_tile: int = 16) -> bool:
+    """A decode-attention kernel (GQA's or MLA's) runs compiled on a TPU, on
+    an unsharded ``(L, B, S, ..., width)`` cache stack with a lane-aligned
+    width (a head, or the latent) and whole tiles of positions: 16 where a
+    block holds them as rows, 128 where it holds them as lanes (MLA's rope
+    keys)."""
+    S_cache, width = stack.shape[2], stack.shape[-1]
     return (kernel_backend() == "pallas" and current_mesh() is None
-            and Dh % 128 == 0 and S_cache % 16 == 0)
+            and width % 128 == 0 and S_cache % positions_tile == 0)
 
 
 # -- SwiGLU MLP -----------------------------------------------------------

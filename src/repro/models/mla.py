@@ -12,6 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.mla_decode_attention import mla_decode_attention
+from repro.models import layers
 from repro.models.flash import flash_attention
 from repro.models.layers import (NEG_INF, Params, _init, rmsnorm, rope,
                                  rope_cos_scale, rope_inv_freq, yarn_mscale)
@@ -111,12 +113,17 @@ def mla_decode_step(
     ``[layer, lane, pos]``, and the scores and context read layer ``layer``
     straight from the stacks: a layer loop that carries the stacks updates
     them in place instead of slicing each layer out and stacking it back.
+    Compiled on a TPU, attention is the
+    :func:`~repro.kernels.mla_decode_attention.mla_decode_attention` kernel,
+    which DMAs the layer's latent from the stacks once, only up to each
+    lane's position, and keeps its scores in VMEM; elsewhere it is
+    :func:`absorbed_attention` on ``c_stack[layer]``, the same arithmetic
+    over every position.
     """
     B = x.shape[0]
     H = cfg.n_heads
     nope, vh = cfg.qk_nope_head_dim, cfg.v_head_dim
     kr = cfg.kv_lora_rank
-    S_max = c_stack.shape[2]
 
     per_lane = jnp.ndim(pos) > 0
     positions = (jnp.reshape(pos, (B, 1)) if per_lane
@@ -133,24 +140,39 @@ def mla_decode_step(
         kr_stack = jax.lax.dynamic_update_slice(kr_stack, kr_new[None], at)
     c_stack = constrain(c_stack, "layers", "batch", "kv_len", None)
     kr_stack = constrain(kr_stack, "layers", "batch", "kv_len", None)
-    cache_c, cache_kr = c_stack[layer], kr_stack[layer]
 
     wkv_b = p["wkv_b"].reshape(kr, H, nope + vh)
     w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
 
     # absorb W_uk into q: score directly against the latent cache
     q_c = jnp.einsum("bqhn,lhn->bqhl", q_nope, w_uk)  # (B,1,H,kr)
+    n_valid = positions[:, 0] + 1
+    if layers.decode_kernel_applies(c_stack, positions_tile=128):
+        ctx = mla_decode_attention(q_c[:, 0], q_rope[:, 0], c_stack, kr_stack,
+                                   layer, n_valid,
+                                   scale=softmax_scale(cfg))[:, None]
+    else:
+        ctx = absorbed_attention(q_c, q_rope, c_stack[layer], kr_stack[layer],
+                                 n_valid, softmax_scale(cfg))
+    out = jnp.einsum("bqhl,lhv->bqhv", ctx, w_uv)       # (B,1,H,vh)
+    out = out.reshape(B, 1, H * vh) @ p["wo"]
+    return out, c_stack, kr_stack
+
+
+def absorbed_attention(q_c, q_rope, cache_c, cache_kr, n_valid, scale):
+    """The absorbed attention in jnp: queries ``q_c`` ``(B,1,H,kr)`` and
+    ``q_rope`` ``(B,1,H,rdim)`` against one layer's latent ``(B,S,kr)`` and
+    rope keys ``(B,S,rdim)``, lane b over positions ``[0, n_valid[b])``.
+    Scores and softmax in float32, the probabilities in the query's dtype.
+    Returns the context ``(B,1,H,kr)``."""
     f32 = jnp.float32
     scores = (
         jnp.einsum("bqhl,bsl->bhqs", q_c, cache_c, preferred_element_type=f32)
         + jnp.einsum("bqhr,bsr->bhqs", q_rope, cache_kr,
                      preferred_element_type=f32)
-    ) * softmax_scale(cfg)
-    valid = jnp.arange(S_max)[None, :] <= positions  # (B,S)
+    ) * scale
+    valid = jnp.arange(cache_c.shape[1])[None, :] < n_valid[:, None]  # (B,S)
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q_c.dtype)
+    return jnp.einsum("bhqs,bsl->bqhl", probs, cache_c)  # (B,1,H,kr)
 
-    ctx = jnp.einsum("bhqs,bsl->bqhl", probs, cache_c)  # (B,1,H,kr)
-    out = jnp.einsum("bqhl,lhv->bqhv", ctx, w_uv)       # (B,1,H,vh)
-    out = out.reshape(B, 1, H * vh) @ p["wo"]
-    return out, c_stack, kr_stack

@@ -1,7 +1,8 @@
 """DeepSeek-V3 on the serving path, at a small size on the CPU: the
 group-limited sigmoid gate, the chip's share of the experts, YaRN, the
-dropless held-expert layer, the in-place latent-cache step, the held-expert
-kernel, and lane-mode serving against the benchmark's plain reference."""
+dropless held-expert layer, the in-place latent-cache step, the MLA
+decode-attention and held-expert kernels, and lane-mode serving against the
+benchmark's plain reference."""
 import dataclasses
 import json
 import math
@@ -15,6 +16,7 @@ import pytest
 
 from repro.configs import get_config, reduced_config
 from repro.core.telemetry import Telemetry
+from repro.kernels.mla_decode_attention import mla_decode_attention
 from repro.kernels.moe_experts import held_experts
 from repro.models import get_model
 from repro.models import layers as L
@@ -242,6 +244,92 @@ def test_inplace_mla_step_matches_slice_and_stack_oracle(per_lane):
     for key in ("c", "kr"):
         np.testing.assert_allclose(ours[key], theirs[key], atol=1e-5)
     np.testing.assert_array_equal(ours["pos"], theirs["pos"])
+
+
+@pytest.mark.parametrize("per_lane", [True, False])
+def test_mla_step_through_kernel_matches_jnp_path(monkeypatch, per_lane):
+    """The branch a TPU takes, the MLA decode-attention kernel reading each
+    layer from the carried latent stacks (here interpreted, 8-position
+    blocks of a 24-position cache), against the jnp path on the same
+    config: logits and latent stacks of 12 steps, each step from the jnp
+    path's cache so that the gap is one step's. Float32, with the softmax
+    taken online: 1e-5 of the logits' scale."""
+    cfg = _cfg(n_layers=3, n_experts_held=8, expert_shard=1)
+    model = get_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0), cfg)
+    lanes, max_len = 3, 24
+    cache = model.init_decode_cache(cfg, lanes, max_len)
+    kc, kk = jax.random.split(jax.random.PRNGKey(1))
+    cache["c"] = jax.random.normal(kc, cache["c"].shape)
+    cache["kr"] = jax.random.normal(kk, cache["kr"].shape)
+    cache["pos"] = (jnp.array([0, 5, 9], jnp.int32) if per_lane
+                    else jnp.int32(4))
+    toks = jax.random.randint(jax.random.PRNGKey(2), (12, lanes, 1), 0,
+                              cfg.vocab_size)
+
+    def step_fn():
+        return jax.jit(lambda p, c, t: model.decode_step(p, c, t, cfg,
+                                                         moe_groups=1))
+
+    jnp_step, kernel_step = step_fn(), step_fn()
+    want = cache
+    for t in range(12):
+        with monkeypatch.context() as m:
+            m.setattr(L, "decode_kernel_applies", lambda *a, **k: True)
+            lg, got = kernel_step(params, want, toks[t])
+        ref, want = jnp_step(params, want, toks[t])
+        scale = float(jnp.abs(ref).max())
+        np.testing.assert_allclose(lg, ref, atol=1e-5 * scale)
+        for key in ("c", "kr"):
+            np.testing.assert_allclose(got[key], want[key], atol=1e-5)
+        np.testing.assert_array_equal(got["pos"], want["pos"])
+
+
+# -- the MLA decode-attention kernel ------------------------------------------------
+@pytest.mark.parametrize("n_valid", [
+    [1, 1, 1, 1],
+    [16, 32, 48, 16],
+    [17, 33, 17, 33],
+    [48, 48, 48, 48],
+    [1, 16, 17, 40],
+    40,
+], ids=["one", "block-end", "block-end-plus-one", "whole", "mixed",
+        "scalar-pos"])
+@pytest.mark.parametrize("H,kr", [(8, 128), (8, 512), (16, 128), (16, 512)])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_mla_decode_attention_matches_jnp(dtype, tol, H, kr, n_valid):
+    """The kernel in interpret mode, reading layer 1 of two-layer latent
+    stacks of 48 positions in their three 16-position blocks, against the
+    jnp absorbed attention on that layer. Every position past a lane's
+    ``n_valid``, and all of layer 0, is NaN in the kernel's stacks: the
+    output stays finite, so the clamped blocks are not read into it and the
+    last block's tail is masked. The scalar-pos case is the form a scalar
+    ``pos`` takes, ``pos + 1`` for every lane. Tolerance relative to the
+    output's largest magnitude: float32 rounding of an online softmax, or a
+    bf16 ulp of the probabilities and of the output."""
+    B, S, rdim = 4, 48, 64
+    if isinstance(n_valid, int):
+        n_valid = jnp.broadcast_to(jnp.int32(n_valid - 1) + 1, (B,))
+    n_valid = jnp.asarray(n_valid, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(H + kr), 4)
+    q_c = jax.random.normal(ks[0], (B, H, kr)).astype(dtype)
+    q_rope = jax.random.normal(ks[1], (B, H, rdim)).astype(dtype)
+    c = jax.random.normal(ks[2], (2, B, S, kr)).astype(dtype)
+    kr_ = jax.random.normal(ks[3], (2, B, S, rdim)).astype(dtype)
+    scale = 1.0 / math.sqrt(kr + rdim)
+    past = (jnp.arange(S)[None, :] >= n_valid[:, None])[None, :, :, None]
+    past = past | (jnp.arange(2) == 0)[:, None, None, None]
+    c_nan = jnp.where(past, jnp.nan, c).astype(dtype)
+    kr_nan = jnp.where(past, jnp.nan, kr_).astype(dtype)
+    got = mla_decode_attention(q_c, q_rope, c_nan, kr_nan, jnp.int32(1),
+                               n_valid, scale=scale, interpret=True)
+    want = MLA.absorbed_attention(q_c[:, None], q_rope[:, None], c[1], kr_[1],
+                                  n_valid, scale)[:, 0]
+    assert got.shape == want.shape and got.dtype == dtype
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, atol=tol * np.abs(want).max())
 
 
 # -- the held-expert kernel -------------------------------------------------------

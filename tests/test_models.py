@@ -194,7 +194,7 @@ def test_gqa_decode_step_kernel_matches_unscanned(monkeypatch, per_lane, arch,
     difference can flip the router's top-k, and through expert capacity
     another lane's output, a discrete change that no tolerance bounds."""
     from repro.models import layers as L
-    monkeypatch.setattr(L, "_decode_kernel_applies", lambda k_stack: True)
+    monkeypatch.setattr(L, "decode_kernel_applies", lambda k_stack: True)
     cfg, params, cache, tokens, step, oracle = _decode_case(
         arch, max_len, overrides, dtype, per_lane)
 

@@ -154,9 +154,9 @@ def _deepseek_cell_config():
                                n_experts_held=8, mtp_depth=0)
 
 
-def _top_level_results(hlo: str) -> list[str]:
+def _top_level_results(hlo: str, op: str | None = None) -> list[str]:
     """Result shapes of the ops outside fused computations: the arrays the
-    program materializes."""
+    program materializes (only those of opcode ``op``, if given)."""
     fused, comps, cur = set(), {}, None
     for line in hlo.splitlines():
         if not line.startswith(" ") and line.rstrip().endswith("{"):
@@ -168,7 +168,8 @@ def _top_level_results(hlo: str) -> list[str]:
                 fused.update(re.findall(r"calls=%([\w.\-]+)", line))
     return [m.group(1) for name, lines in comps.items() if name not in fused
             for line in lines
-            if (m := re.search(r"= (\w+\[[\d,]*\])", line))]
+            if (m := re.search(r"= (\w+\[[\d,]*\])\S* ([\w\-]+)\(", line))
+            and op in (None, m.group(2))]
 
 
 def test_deepseek_lane_step_fits_and_reads_latent_in_place(shape_on,
@@ -220,3 +221,68 @@ def test_held_expert_kernel_deepseek_share(shape_on):
                         shape_on((E,), jnp.int32),
                         shape_on((), jnp.int32)).compile()
     assert _has_kernel(compiled)
+
+
+def test_mla_decode_attention_deepseek_cell(shape_on):
+    """The MLA decode-attention kernel at the deepseek cell's widths: 64
+    lanes, 128 heads, a 512 + 64 latent, 4096 positions, 7 layers' stacks.
+    The compiler refuses blocks that do not fit VMEM."""
+    from repro.kernels.mla_decode_attention import mla_decode_attention
+    from repro.models.mla import softmax_scale
+
+    cfg = _deepseek_cell_config()
+    B, H, S = 64, cfg.n_heads, 4096
+    kr, rdim = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    fn = jax.jit(functools.partial(mla_decode_attention,
+                                   scale=softmax_scale(cfg), interpret=False))
+    compiled = fn.lower(shape_on((B, H, kr)), shape_on((B, H, rdim)),
+                        shape_on((cfg.n_layers, B, S, kr)),
+                        shape_on((cfg.n_layers, B, S, rdim)),
+                        shape_on((), jnp.int32),
+                        shape_on((B,), jnp.int32)).compile()
+    assert _has_kernel(compiled)
+
+
+def test_deepseek_lane_step_runs_mla_kernel(shape_on, monkeypatch):
+    """The cell's donating lane step (7 layers, 64 lanes, 4096 positions)
+    runs the MLA decode-attention kernel, and materializes no float32
+    scores of every lane, head and position: they stay in the kernel's
+    VMEM. The kernel reads the latent stacks in place: they alias the
+    output, and no stack is copied around the custom call, not even the
+    rope keys' into another layout: the step's temporaries stay under the
+    smaller stack."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    cfg = _deepseek_cell_config()
+    model = get_model(cfg)
+    place = functools.partial(jax.tree.map,
+                              lambda s: shape_on(s.shape, s.dtype))
+    params = place(jax.eval_shape(
+        functools.partial(model.init_params, cfg=cfg), jax.random.PRNGKey(0)))
+    lanes, max_len = 64, 4096
+    cache = dict(jax.eval_shape(
+        functools.partial(model.init_decode_cache, cfg, lanes, max_len)))
+    cache["pos"] = jax.ShapeDtypeStruct((lanes,), jnp.int32)
+    cache = place(cache)
+    step = jax.jit(lambda p, c, t: model.decode_step(p, c, t, cfg,
+                                                     moe_groups=1),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, cache,
+                          shape_on((lanes, 1), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "mla_decode_attention" in hlo
+    mem = compiled.memory_analysis()
+    c_bytes, kr_bytes = (cache[k].size * cache[k].dtype.itemsize
+                         for k in ("c", "kr"))
+    assert mem.alias_size_in_bytes >= c_bytes + kr_bytes
+    assert mem.temp_size_in_bytes < kr_bytes
+    stacks = [sorted((cfg.n_layers, lanes, max_len, w))
+              for w in (cfg.kv_lora_rank, cfg.qk_rope_head_dim)]
+    for op in ("copy", "transpose"):
+        for shape in _top_level_results(hlo, op):
+            dims = re.fullmatch(r"\w+\[([\d,]*)\]", shape).group(1)
+            assert sorted(int(d) for d in dims.split(",") if d) not in stacks
+    scores = sorted((lanes, cfg.n_heads, max_len))
+    for shape in _top_level_results(hlo):
+        dtype, dims = re.fullmatch(r"(\w+)\[([\d,]*)\]", shape).groups()
+        dims = sorted(int(d) for d in dims.split(",") if d not in ("", "1"))
+        assert not (dtype == "f32" and dims == scores), shape
